@@ -1,7 +1,34 @@
 """Shared benchmark utilities: CSV emission per the harness contract
 (``name,us_per_call,derived``)."""
+import json
+import os
+import subprocess
 import sys
 import time
+
+# label of rows measured in a child on 8 virtual CPU devices
+CPU8 = "device=cpu:8"
+
+
+def run_cpu_helper(code: str, timeout: int):
+    """Run ``code`` in a child Python on 8 virtual CPU devices and return
+    the JSON it prints after ``JSON``; raise if the child fails.
+
+    The child models an 8-device cluster and is held to the CPU: a chip
+    belongs to one process, and on a machine with one that is not a child
+    of this harness."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = os.path.abspath("src") + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=timeout, env=env)
+    for line in proc.stdout.splitlines():
+        if line.startswith("JSON"):
+            return json.loads(line[4:])
+    raise RuntimeError(f"helper failed (rc={proc.returncode}): "
+                       f"{proc.stderr[-400:]}")
 
 
 def emit(name: str, us_per_call: float, derived: str = ""):

@@ -2,9 +2,11 @@
 (load-balance / checkpoint / restart / restore).
 
 (a) REAL measurements: ElasticTrainer shrink/expand on virtual devices
-    (subprocess, 8 devices) across replica counts and model sizes — the JAX
-    analog of the paper's Jacobi runs, including the paper's headline
-    findings (restart dominates small problems; in-memory ckpt/restore cheap).
+    (subprocess on 8 CPU devices, rows labelled ``device=cpu:8``) across
+    replica counts and model sizes — the JAX analog of the paper's Jacobi
+    runs, including the paper's headline findings (restart dominates small
+    problems; in-memory ckpt/restore cheap).  Then the fused Pallas pack vs.
+    per-leaf snapshot, in this process on the device it holds.
 (b) The calibrated analytic model the simulator uses (paper shapes 5a/5b/5c).
 (c) Per-phase makespan decomposition of traced simulator runs — where the
     overhead of (a)/(b) actually lands in end-to-end completion time — with
@@ -12,15 +14,12 @@
     priority-weighted mean completion to <0.1% (same invariant the trace
     auditor enforces).
 
-``run(sim_only=True)`` (the harness ``--fast`` path / CI) skips the live
-subprocess section (a) and keeps (b) and (c).
+``run(sim_only=True)`` (the harness ``--fast`` path / CI) skips section (a)
+and keeps (b) and (c).
 """
-import json
-import os
-import subprocess
-import sys
+import time
 
-from benchmarks.common import emit, kv, phases_kv
+from benchmarks.common import CPU8, emit, kv, phases_kv, run_cpu_helper
 
 HELPER = r"""
 import json
@@ -47,80 +46,50 @@ for arch, width in [("yi-6b", 64), ("yi-6b", 128)]:
 print("JSON" + json.dumps(out))
 """
 
-KERNEL_HELPER = r"""
-import json, time
-import jax, jax.numpy as jnp, numpy as np
-from repro.checkpoint.reshard import snapshot_to_host
-from repro.kernels.pack import packed_snapshot_to_host
-
-# on CPU the Pallas kernel runs in interpret mode (Python-speed, validation
-# only); the packed-vs-perleaf ratio is meaningful on a real TPU backend
-mode = "compiled" if jax.default_backend() == "tpu" else "interpret"
-rng = np.random.default_rng(0)
-def tree_of(n_leaves, leaf_elems):
-    return {f"layer{i:02d}": {"w": jnp.asarray(
-        rng.standard_normal(leaf_elems).astype(np.float32))}
-        for i in range(n_leaves)}
-
-out = []
-for n_leaves, leaf_elems in [(16, 4096), (64, 4096), (64, 65536)]:
-    tree = tree_of(n_leaves, leaf_elems)
-    for name, fn in [("perleaf", lambda t: snapshot_to_host(t)),
-                     ("packed", lambda t: packed_snapshot_to_host(t))]:
-        fn(tree)                                    # warm (trace/compile)
-        t0 = time.perf_counter(); reps = 3
-        for _ in range(reps):
-            fn(tree)
-        dt = (time.perf_counter() - t0) / reps
-        out.append(dict(kind=name, leaves=n_leaves, elems=leaf_elems,
-                        seconds=dt, mode=mode))
-print("JSON" + json.dumps(out))
-"""
-
-
-def _helper_rows(code: str, tag: str):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.abspath("src") + os.pathsep + \
-        env.get("PYTHONPATH", "")
-    proc = subprocess.run([sys.executable, "-c", code],
-                          capture_output=True, text=True, timeout=1800,
-                          env=env)
-    for line in proc.stdout.splitlines():
-        if line.startswith("JSON"):
-            return json.loads(line[4:])
-    emit(f"fig5.{tag}.FAILED", 0.0, proc.stderr[-200:].replace(",", ";"))
-    return []
-
 
 def _live_rows():
-    for r in _helper_rows(HELPER, "live"):
+    for r in run_cpu_helper(HELPER, timeout=1800):
         kind = "shrink" if r["r1"] < r["r0"] else "expand"
         name = (f"fig5.live.{kind}.w{r['width']}.{r['r0']}to{r['r1']}"
                 f".{r['path']}")
         emit(name, r["total"] * 1e6,
              f"lb={r['load_balance']:.3f};ckpt={r['checkpoint']:.3f};"
-             f"restart={r['restart']:.3f};restore={r['restore']:.3f}")
+             f"restart={r['restart']:.3f};restore={r['restore']:.3f};{CPU8}")
 
 
 def _kernel_rows():
     """Slow-lane fig5 kernel section: fused Pallas pack vs. per-leaf
-    device_get for the device->host snapshot (grounds the fast-lane
-    reshard-bandwidth constants)."""
-    rows = _helper_rows(KERNEL_HELPER, "kernel")
-    by_case = {}
-    mode = rows[0]["mode"] if rows else "?"
-    for r in rows:
-        name = f"fig5.kernel.snapshot.{r['kind']}.l{r['leaves']}x{r['elems']}"
-        emit(name, r["seconds"] * 1e6,
-             f"leaves={r['leaves']};elems={r['elems']};mode={r['mode']}")
-        by_case.setdefault((r["leaves"], r["elems"]), {})[r["kind"]] = \
-            r["seconds"]
-    for (leaves, elems), d in sorted(by_case.items()):
-        if "perleaf" in d and "packed" in d:
-            emit(f"fig5.kernel.pack_speedup.l{leaves}x{elems}", 0.0,
-                 kv(f"{d['perleaf'] / d['packed']:.2f}x",
-                    perleaf_s=d["perleaf"], packed_s=d["packed"], mode=mode))
+    device_get for the device->host snapshot, in this process.  On a CPU
+    the kernel runs in interpret mode (Python speed, validation only); the
+    ratio means something on a TPU."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.checkpoint.reshard import snapshot_to_host
+
+    dev = jax.devices()[0]
+    label = f"device={dev.platform}:{dev.device_kind}"
+    rng = np.random.default_rng(0)
+    for n_leaves, leaf_elems in [(16, 4096), (64, 4096), (64, 65536)]:
+        tree = {f"layer{i:02d}": {"w": jnp.asarray(
+            rng.standard_normal(leaf_elems).astype(np.float32))}
+            for i in range(n_leaves)}
+        secs = {}
+        for name, fused in [("perleaf", False), ("packed", True)]:
+            snapshot_to_host(tree, fused=fused)        # warm (trace/compile)
+            t0 = time.perf_counter()
+            reps = 3
+            for _ in range(reps):
+                snapshot_to_host(tree, fused=fused)
+            secs[name] = (time.perf_counter() - t0) / reps
+            emit(f"fig5.kernel.snapshot.{name}.l{n_leaves}x{leaf_elems}",
+                 secs[name] * 1e6,
+                 f"leaves={n_leaves};elems={leaf_elems};{label}")
+        emit(f"fig5.kernel.pack_speedup.l{n_leaves}x{leaf_elems}", 0.0,
+             kv(f"{secs['perleaf'] / secs['packed']:.2f}x",
+                perleaf_s=secs["perleaf"], packed_s=secs["packed"]) +
+             f";{label}")
 
 
 def _sim_phase_rows():

@@ -1,14 +1,12 @@
 """Paper Fig. 6 — per-iteration timeline across a shrink and an expand.
 
-Real run on virtual devices: iteration times rise after shrink, fall after
-expand; the rescale gaps are the measured overheads.
+Real run on 8 virtual CPU devices (rows labelled ``device=cpu:8``):
+iteration times rise after shrink, fall after expand; the rescale gaps are
+the measured overheads.
 """
-import json
-import os
-import subprocess
 import sys
 
-from benchmarks.common import emit
+from benchmarks.common import CPU8, emit, run_cpu_helper
 
 HELPER = r"""
 import json, time
@@ -38,32 +36,20 @@ print("JSON" + json.dumps(events))
 
 
 def run():
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.abspath("src") + os.pathsep + \
-        env.get("PYTHONPATH", "")
-    proc = subprocess.run([sys.executable, "-c", HELPER],
-                          capture_output=True, text=True, timeout=1800,
-                          env=env)
-    events = []
-    for line in proc.stdout.splitlines():
-        if line.startswith("JSON"):
-            events = json.loads(line[4:])
-    if not events:
-        emit("fig6.timeline.FAILED", 0.0, proc.stderr[-200:].replace(",", ";"))
-        return
+    events = run_cpu_helper(HELPER, timeout=1800)
     phase, buf = 0, []
     for kind, replicas, dt in events:
         if kind == "step":
             buf.append(dt)
         else:
             emit(f"fig6.phase{phase}.steps.r{buf and len(buf)}",
-                 1e6 * sum(buf) / len(buf), f"replicas_before={replicas}")
-            emit(f"fig6.{kind}", dt * 1e6, f"to_replicas={replicas}")
+                 1e6 * sum(buf) / len(buf),
+                 f"replicas_before={replicas};{CPU8}")
+            emit(f"fig6.{kind}", dt * 1e6, f"to_replicas={replicas};{CPU8}")
             phase += 1
             buf = []
     if buf:
-        emit(f"fig6.phase{phase}.steps", 1e6 * sum(buf) / len(buf), "")
+        emit(f"fig6.phase{phase}.steps", 1e6 * sum(buf) / len(buf), CPU8)
     # render the measured run as a flight-recorder timeline (stderr keeps
     # the stdout CSV clean); the trace records mirror what a live tracer
     # would have emitted for this one-job shrink/expand story

@@ -77,6 +77,7 @@ def main() -> None:
     only = {s.strip() for s in args.only.split(",") if s.strip()}
 
     print("name,us_per_call,derived")
+    failed = []
     for name, module in MODULES:
         if only and name not in only:
             continue
@@ -104,8 +105,13 @@ def main() -> None:
             if args.profile:
                 _emit_profile(name, prof)
         except Exception as e:
+            # keep going so one broken table does not hide the others' rows,
+            # but the run as a whole fails
+            failed.append(name)
             print(f"{name}.ERROR,0.0,{e!r}"[:400].replace("\n", " "))
             traceback.print_exc(file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmark modules failed: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -6,14 +6,9 @@ The live run uses 8 slots and tiny jobs; absolute numbers differ from the
 64-vCPU EKS cluster, but the table's *orderings* are the reproduction target
 (DESIGN.md §6.5).
 """
-import json
-import os
-import subprocess
-import sys
-
 import numpy as np
 
-from benchmarks.common import emit, metrics_kv
+from benchmarks.common import CPU8, emit, metrics_kv, run_cpu_helper
 
 LIVE_HELPER = r"""
 import json, math
@@ -88,22 +83,8 @@ def run():
             prefixes=("percentiles.resp_p99", "phase_seconds.")))
 
     # --- "actual" columns: live controller with real training jobs ----------
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.abspath("src") + os.pathsep + \
-        env.get("PYTHONPATH", "")
-    proc = subprocess.run([sys.executable, "-c", LIVE_HELPER],
-                          capture_output=True, text=True, timeout=3600,
-                          env=env)
-    data = {}
-    for line in proc.stdout.splitlines():
-        if line.startswith("JSON"):
-            data = json.loads(line[4:])
-    if not data:
-        emit("table1.live.FAILED", 0.0, proc.stderr[-200:].replace(",", ";"))
-        return
-    for v, m in data.items():
+    for v, m in run_cpu_helper(LIVE_HELPER, timeout=3600).items():
         emit(f"table1.live.{v}", m["total"] * 1e6,
              f"util={m['util']:.3f};resp={m['resp']:.2f};"
              f"compl={m['compl']:.2f};rescales={m['rescales']};"
-             f"dropped={m['dropped']}")
+             f"dropped={m['dropped']};{CPU8}")

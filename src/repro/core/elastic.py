@@ -232,6 +232,12 @@ class ElasticTrainer:
         return metrics
 
     @property
+    def compiled_step(self):
+        """The AOT-compiled train step of the current mesh, for
+        ``memory_analysis()`` and ``as_text()``."""
+        return self._compiled
+
+    @property
     def done(self) -> bool:
         return self.step_idx >= self.job.total_steps
 
@@ -317,6 +323,12 @@ class ElasticTrainer:
         """Join all pending async checkpoint writes (preempt-time barrier)."""
         if self._async_ckpt is not None:
             self._async_ckpt.barrier()
+
+    def close(self) -> None:
+        """Publish pending async checkpoints and stop their writer thread."""
+        if self._async_ckpt is not None:
+            self._async_ckpt.close()
+            self._async_ckpt = None
 
     def restore_disk(self, store, job_id: str) -> int:
         """Restart-from-checkpoint (the paper's extra restart flag)."""

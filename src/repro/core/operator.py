@@ -342,6 +342,11 @@ class ElasticClusterController:
     def _complete(self, job: JobState):
         freed = job.replicas
         self.cluster.release_slots(job.job_id)
+        # free the finished job's parameters and optimizer state before the
+        # policy hands its slots (and their device memory) to the next job
+        live = self.live[job.job_id]
+        live.trainer.close()
+        live.trainer = None
         job.status = JobStatus.COMPLETED
         job.end_time = self.now
         job.replicas = 0

@@ -24,6 +24,8 @@ import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Sequence, Tuple
 
+from repro.utils.roofline import V5E_KIND, peaks
+
 
 def interp_piecewise(points: Sequence[Tuple[float, float]], x: float) -> float:
     """Piecewise-linear interpolation with flat extrapolation."""
@@ -218,9 +220,7 @@ class RescaleModel:
 # TPU training jobs (ties the scheduler to this framework's archs)
 # ---------------------------------------------------------------------------
 
-V5E_PEAK_FLOPS = 197e12
-V5E_HBM_BW = 819e9
-V5E_ICI_BW = 50e9
+V5E = peaks(V5E_KIND)
 CHIPS_PER_REPLICA = 16             # one model-parallel group (DESIGN.md §2)
 
 
@@ -239,11 +239,11 @@ class ArchScalingModel:
 
     def time_per_step(self, groups: int) -> float:
         compute = self.flops_per_step / (
-            groups * CHIPS_PER_REPLICA * V5E_PEAK_FLOPS * self.mfu)
+            groups * CHIPS_PER_REPLICA * V5E.peak_flops * self.mfu)
         # data-parallel gradient ring all-reduce across groups
         if groups > 1:
             comm = 2 * self.param_bytes * (groups - 1) / groups / (
-                CHIPS_PER_REPLICA * V5E_ICI_BW)
+                CHIPS_PER_REPLICA * V5E.ici_bw)
         else:
             comm = 0.0
         return compute + max(comm, 0.0)

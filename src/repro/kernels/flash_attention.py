@@ -12,13 +12,14 @@ so repeated KV is never materialized in HBM or VMEM.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.platform import on_platform
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -72,8 +73,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, scale: float = None,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = False):
+                        interpret: Optional[bool] = None):
     """q: (B,S,H,hd); k,v: (B,S,KV,hd) -> (B,S,H,hd)."""
+    return on_platform(
+        functools.partial(_flash_fwd, causal=causal, scale=scale,
+                          block_q=block_q, block_k=block_k),
+        q, k, v, interpret=interpret)
+
+
+def _flash_fwd(q, k, v, *, causal: bool, scale: Optional[float],
+               block_q: int, block_k: int, interpret: bool):
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
@@ -104,7 +113,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, scale: float = None,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,9 +1,10 @@
 """Jitted wrappers around the Pallas kernels, with a global enable switch.
 
-On this CPU container, kernels run in ``interpret=True`` mode for validation;
-on a real TPU backend they compile natively.  Model code consults
-``pallas_enabled()`` — default off on CPU so the dry-run lowers the pure-XLA
-path (a TPU Pallas kernel cannot lower on the CPU backend; see DESIGN.md §5).
+Each kernel compiles natively where it is lowered for a TPU and runs in
+interpret mode elsewhere (``kernels/platform.py``).  Model code consults
+``pallas_enabled()``, which is off unless ``REPRO_USE_PALLAS=1`` or
+``set_pallas(True)``: off, train and prefill take the XLA path
+(``kernels/blocked.py``, the jnp SSD scan).
 
 The flash-attention wrapper attaches a custom VJP whose backward pass
 recomputes attention via the memory-efficient reference path (flash-style
@@ -13,6 +14,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -22,24 +24,15 @@ from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_chunked_pallas
 
-_STATE = {
-    "enabled": os.environ.get("REPRO_USE_PALLAS", "0") == "1",
-    "interpret": jax.default_backend() != "tpu",
-}
+_STATE = {"enabled": os.environ.get("REPRO_USE_PALLAS", "0") == "1"}
 
 
 def pallas_enabled() -> bool:
     return _STATE["enabled"]
 
 
-def set_pallas(enabled: bool, *, interpret: bool = None):
+def set_pallas(enabled: bool):
     _STATE["enabled"] = enabled
-    if interpret is not None:
-        _STATE["interpret"] = interpret
-
-
-def _interp(override):
-    return _STATE["interpret"] if override is None else override
 
 
 # ---------------------------------------------------------------------------
@@ -70,9 +63,9 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
-                    interpret: bool = None):
+                    interpret: Optional[bool] = None):
     scale = q.shape[-1] ** -0.5 if scale is None else scale
-    return _flash(q, k, v, causal, scale, _interp(interpret))
+    return _flash(q, k, v, causal, scale, interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -104,13 +97,14 @@ def _ssd_bwd(chunk, interpret, res, g):
 _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 
-def ssd(x, dt, a_log, b, c, *, chunk: int = 128, interpret: bool = None):
-    return _ssd(x, dt, a_log, b, c, chunk, _interp(interpret))
+def ssd(x, dt, a_log, b, c, *, chunk: int = 128,
+        interpret: Optional[bool] = None):
+    return _ssd(x, dt, a_log, b, c, chunk, interpret)
 
 
 # ---------------------------------------------------------------------------
 # RMSNorm
 # ---------------------------------------------------------------------------
 
-def rmsnorm(x, w, *, eps: float = 1e-5, interpret: bool = None):
-    return rmsnorm_pallas(x, w, eps=eps, interpret=_interp(interpret))
+def rmsnorm(x, w, *, eps: float = 1e-5, interpret: Optional[bool] = None):
+    return rmsnorm_pallas(x, w, eps=eps, interpret=interpret)

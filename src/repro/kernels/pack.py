@@ -20,7 +20,7 @@ body copies the active leaf's block to the output tile.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,13 +28,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.checkpoint.reshard import flatten_tree
+from repro.kernels.platform import on_platform
 
 LANE = 128
 BLOCK_ROWS = 8
-
-
-def _interp(override):
-    return (jax.default_backend() != "tpu") if override is None else override
 
 
 def _pack_kernel(*refs, starts: Tuple[int, ...], nblocks: Tuple[int, ...]):
@@ -48,10 +45,16 @@ def _pack_kernel(*refs, starts: Tuple[int, ...], nblocks: Tuple[int, ...]):
 
 def pack_leaves_pallas(leaves: Sequence[jax.Array], *,
                        block_rows: int = BLOCK_ROWS, lane: int = LANE,
-                       interpret: bool = None) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """Pack same-dtype ``leaves`` into one ``(total_blocks·block_rows, lane)``
     device buffer, leaf-major, each leaf zero-padded to a block multiple."""
-    interpret = _interp(interpret)
+    return on_platform(
+        functools.partial(_pack_call, block_rows=block_rows, lane=lane),
+        *leaves, interpret=interpret)
+
+
+def _pack_call(*leaves: jax.Array, block_rows: int, lane: int,
+               interpret: bool) -> jax.Array:
     block = block_rows * lane
     views, nblocks = [], []
     for leaf in leaves:
@@ -98,8 +101,20 @@ def pack_leaves_ref(leaves: Sequence[jax.Array], *,
     return jnp.concatenate(parts).reshape(-1, lane)
 
 
+def _on_one_device(a: jax.Array) -> jax.Array:
+    """``a`` on one device, since a Pallas TPU call cannot be partitioned:
+    a replicated array gives its first copy (nothing moves), a sharded one
+    is gathered onto its first shard's device."""
+    if len(a.sharding.device_set) == 1:
+        return a
+    first = a.addressable_shards[0]
+    if a.sharding.is_fully_replicated:
+        return first.data
+    return jax.device_put(a, jax.sharding.SingleDeviceSharding(first.device))
+
+
 def packed_snapshot_to_host(tree, *, block_rows: int = BLOCK_ROWS,
-                            lane: int = LANE, interpret: bool = None
+                            lane: int = LANE, interpret: Optional[bool] = None
                             ) -> Dict[str, np.ndarray]:
     """Fused device→host snapshot: one packed transfer per dtype group.
 
@@ -107,7 +122,7 @@ def packed_snapshot_to_host(tree, *, block_rows: int = BLOCK_ROWS,
     flat = flatten_tree(tree)
     block = block_rows * lane
     groups: Dict[str, List[str]] = {}
-    arrs = {k: jnp.asarray(v) for k, v in flat.items()}
+    arrs = {k: _on_one_device(jnp.asarray(v)) for k, v in flat.items()}
     out: Dict[str, np.ndarray] = {}
     for k, a in arrs.items():
         if a.size == 0:                       # nothing to transfer

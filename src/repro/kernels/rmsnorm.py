@@ -2,10 +2,18 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import on_platform
+
+# bytes of one (block_rows, D) tile at f32: the input and output tiles are
+# double-buffered and the body holds two f32 temporaries of the same size,
+# so ~6 tiles must fit the 16 MiB of VMEM a kernel may use by default
+TILE_BYTES = 1 << 20
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -15,14 +23,25 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def rmsnorm_pallas(x, w, *, eps: float = 1e-5, block_rows: int = 256,
-                   interpret: bool = False):
+def _block_rows(D: int) -> int:
+    """Rows per tile: as many as keep a tile within ``TILE_BYTES``, a
+    multiple of 8 sublanes, at least 8."""
+    return max(8, TILE_BYTES // (4 * D) // 8 * 8)
+
+
+def rmsnorm_pallas(x, w, *, eps: float = 1e-5,
+                   interpret: Optional[bool] = None):
     """x: (..., D); w: (D,)."""
+    return on_platform(functools.partial(_rmsnorm_call, eps=eps), x, w,
+                       interpret=interpret)
+
+
+def _rmsnorm_call(x, w, *, eps: float, interpret: bool):
     orig_shape = x.shape
     D = orig_shape[-1]
     xf = x.reshape(-1, D)
     R = xf.shape[0]
-    block_rows = min(block_rows, R)
+    block_rows = min(_block_rows(D), R)
     pad = (-R) % block_rows
     if pad:
         xf = jnp.pad(xf, ((0, pad), (0, 0)))

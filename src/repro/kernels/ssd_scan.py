@@ -4,51 +4,61 @@ TPU adaptation of the SSD algorithm (arXiv 2405.21060 §6): the sequence is
 processed in chunks of Q tokens.  Within a chunk the dual "attention" form is
 three MXU matmuls ((Q,N)x(N,Q), (Q,Q)x(Q,P), (Q,N)x(N,P)); across chunks the
 (P,N) state is carried in VMEM scratch through the sequentially-iterated chunk
-grid dimension.  Cumulative decays use a lower-triangular ones matmul rather
-than cumsum so everything maps onto the MXU.
+grid dimension.  Cumulative decays are masked sums over a (Q, Q) triangle.
+
+Layout is head-major, like the flash kernel: every per-head operand is
+transposed to ``(B, H, L, ·)`` so each block's last two dims are a chunk of
+the sequence and a full feature dim, which the TPU's (8, 128) tiling accepts.
+``dt`` comes in twice, as a column ``(Q, 1)`` and as a row ``(1, Q)``, so the
+kernel never transposes a vector; ``A = -exp(a_log)`` sits whole in SMEM and
+is read per head.
 
 Grid: (batch, head, chunk) with chunk innermost ("arbitrary" = sequential).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels.platform import on_platform
 
 
-def _ssd_kernel(a_log_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, h_ref, *,
-                chunk: int):
+def _ssd_kernel(a_ref, x_ref, dtc_ref, dtr_ref, b_ref, c_ref, y_ref, h_ref):
+    h_idx = pl.program_id(1)
     n = pl.program_id(2)
 
     @pl.when(n == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    Q = chunk
-    x = x_ref[0, :, 0, :].astype(jnp.float32)            # (Q, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)[:, None]    # (Q, 1)
-    b = b_ref[0, :, 0, :].astype(jnp.float32)            # (Q, N)
-    c = c_ref[0, :, 0, :].astype(jnp.float32)            # (Q, N)
-    A = -jnp.exp(a_log_ref[0].astype(jnp.float32))       # scalar
+    x = x_ref[0, 0].astype(jnp.float32)                  # (Q, P)
+    dt_col = dtc_ref[0, 0].astype(jnp.float32)           # (Q, 1)
+    dt_row = dtr_ref[0, 0].astype(jnp.float32)           # (1, Q)
+    b = b_ref[0, 0].astype(jnp.float32)                  # (Q, N)
+    c = c_ref[0, 0].astype(jnp.float32)                  # (Q, N)
+    A = a_ref[h_idx]                                     # scalar (SMEM)
 
-    dA = dt * A                                          # (Q, 1)
-    # inclusive cumulative sum via lower-triangular ones matmul (MXU-friendly)
+    dA_col = dt_col * A                                  # (Q, 1)
+    dA_row = dt_row * A                                  # (1, Q)
+    Q = x.shape[0]
     rows = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    tril = (rows >= cols).astype(jnp.float32)
-    cum = jax.lax.dot_general(tril, dA, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # (Q,1)
+    causal = rows >= cols
+    # inclusive cumulative sums of dA, as a column and as a row
+    cum_col = jnp.sum(jnp.where(causal, dA_row, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(rows <= cols, dA_col, 0.0), axis=0,
+                      keepdims=True)
 
     # --- intra-chunk quadratic term ---
-    decay = jnp.where(rows >= cols, jnp.exp(cum - cum.T), 0.0)     # (Q,Q)
+    decay = jnp.where(causal, jnp.exp(cum_col - cum_row), 0.0)     # (Q,Q)
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (Q,Q)
-    scores = cb * decay * dt.T                                     # dt_s on cols
+    scores = cb * decay * dt_row                                  # dt_s on cols
     y = jax.lax.dot_general(scores, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)    # (Q,P)
 
@@ -56,20 +66,25 @@ def _ssd_kernel(a_log_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, h_ref, *,
     h_prev = h_ref[...]                                            # (P,N)
     y_inter = jax.lax.dot_general(c, h_prev, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)  # (Q,P)
-    y = y + jnp.exp(cum) * y_inter
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y = y + jnp.exp(cum_col) * y_inter
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # --- state update: h = exp(cum_Q) h_prev + X^T (tail*dt*B) ---
-    total = cum[Q - 1, 0]
-    tail = jnp.exp(total - cum)                                    # (Q,1)
-    hb = jax.lax.dot_general(x, b * (tail * dt), (((0,), (0,)), ((), ())),
+    total = jnp.sum(dA_row, axis=1, keepdims=True)                 # (1,1)
+    tail = jnp.exp(total - cum_col)                                # (Q,1)
+    hb = jax.lax.dot_general(x, b * (tail * dt_col), (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (P,N)
     h_ref[...] = jnp.exp(total) * h_prev + hb
 
 
 def ssd_chunked_pallas(x, dt, a_log, b, c, *, chunk: int = 128,
-                       interpret: bool = False):
+                       interpret: Optional[bool] = None):
     """x: (B,L,H,P); dt: (B,L,H); a_log: (H,); b,c: (B,L,G,N) -> (B,L,H,P)."""
+    return on_platform(functools.partial(_ssd_call, chunk=chunk),
+                       x, dt, a_log, b, c, interpret=interpret)
+
+
+def _ssd_call(x, dt, a_log, b, c, *, chunk: int, interpret: bool):
     B, L, H, P = x.shape
     G, N = b.shape[2], b.shape[3]
     rep = H // G
@@ -77,21 +92,27 @@ def ssd_chunked_pallas(x, dt, a_log, b, c, *, chunk: int = 128,
     assert L % Q == 0, (L, Q)
     nc = L // Q
 
-    kernel = functools.partial(_ssd_kernel, chunk=Q)
-    return pl.pallas_call(
-        kernel,
+    a = -jnp.exp(a_log.astype(jnp.float32))                        # (H,)
+    xt = x.transpose(0, 2, 1, 3)                                   # (B,H,L,P)
+    dtt = dt.transpose(0, 2, 1)                                    # (B,H,L)
+    bt = b.transpose(0, 2, 1, 3)                                   # (B,G,L,N)
+    ct = c.transpose(0, 2, 1, 3)
+    y = pl.pallas_call(
+        _ssd_kernel,
         grid=(B, H, nc),
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, h, n: (h,)),
-            pl.BlockSpec((1, Q, 1, P), lambda bi, h, n: (bi, n, h, 0)),
-            pl.BlockSpec((1, Q, 1), lambda bi, h, n: (bi, n, h)),
-            pl.BlockSpec((1, Q, 1, N), lambda bi, h, n: (bi, n, h // rep, 0)),
-            pl.BlockSpec((1, Q, 1, N), lambda bi, h, n: (bi, n, h // rep, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, Q, P), lambda bi, h, n: (bi, h, n, 0)),
+            pl.BlockSpec((1, 1, Q, 1), lambda bi, h, n: (bi, h, n, 0)),
+            pl.BlockSpec((1, 1, 1, Q), lambda bi, h, n: (bi, h, 0, n)),
+            pl.BlockSpec((1, 1, Q, N), lambda bi, h, n: (bi, h // rep, n, 0)),
+            pl.BlockSpec((1, 1, Q, N), lambda bi, h, n: (bi, h // rep, n, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Q, 1, P), lambda bi, h, n: (bi, n, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, L, H, P), x.dtype),
+        out_specs=pl.BlockSpec((1, 1, Q, P), lambda bi, h, n: (bi, h, n, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, L, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a_log, x, dt, b, c)
+    )(a, xt, dtt[..., None], dtt[:, :, None, :], bt, ct)
+    return y.transpose(0, 2, 1, 3)

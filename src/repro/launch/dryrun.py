@@ -47,8 +47,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     from repro.launch.mesh import chips_in, make_production_mesh
     from repro.utils.flops import cell_flops, cell_hbm_bytes
     from repro.utils.hlo import collective_bytes
-    from repro.utils.roofline import (normalize_cost_analysis,
-                                      roofline_from_analysis)
+    from repro.utils.roofline import V5E_KIND, peaks, roofline_from_analysis
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     mesh_name = "multipod_2x16x16" if multi_pod else "pod_16x16"
@@ -79,7 +78,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
                        + ma.temp_size_in_bytes - ma.alias_size_in_bytes),
     }
     rec["fits_hbm"] = rec["memory"]["peak_bytes"] <= 16e9
-    ca = normalize_cost_analysis(compiled.cost_analysis())
+    ca = compiled.cost_analysis() or {}
     rec["xla_cost"] = {"flops": ca.get("flops", 0.0),
                        "bytes": ca.get("bytes accessed", 0.0)}
 
@@ -97,7 +96,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     terms = roofline_from_analysis(
         {"flops": flops_global / chips, "bytes accessed": hbm_global / chips},
         rec["collectives"].get("total", 0.0),
-        cell.model_flops, chips)
+        cell.model_flops, chips, peaks(V5E_KIND))
     rec["model_flops"] = cell.model_flops
     rec["analytic"] = {"flops_global": flops_global,
                        "hbm_bytes_global": hbm_global}
@@ -119,7 +118,8 @@ def main():
     args = ap.parse_args()
 
     import jax
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
 
     from repro.launch.cells import all_cells

@@ -22,6 +22,8 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get_config, smoke_config
     from repro.models import (decode_step, init_params, pad_cache, prefill)
 

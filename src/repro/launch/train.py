@@ -44,6 +44,8 @@ def main():
             f"--xla_force_host_platform_device_count={args.virtual_devices}")
 
     import jax
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.checkpoint import DiskCheckpointStore
     from repro.configs import get_config, smoke_config
     from repro.core.elastic import ElasticTrainer, TrainJobConfig

@@ -11,19 +11,33 @@ assignment's HLO_FLOPs / (chips x peak) with global numbers.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Dict, Optional
 
 
 @dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12       # bf16 / chip (v5e)
-    hbm_bw: float = 819e9            # bytes/s / chip
-    ici_bw: float = 50e9             # bytes/s / link (effective per chip)
-    hbm_bytes: float = 16e9          # HBM capacity / chip
+    peak_flops: float                # bf16 FLOP/s per chip
+    hbm_bw: float                    # HBM bytes/s per chip
+    ici_bw: float                    # bytes/s per ICI link
 
 
-V5E = HW()
+V5E_KIND = "TPU v5 lite"             # jax's device_kind of a TPU v5e chip
+
+# Published peaks per chip, keyed by ``device_kind``.  TPU v5e: Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of
+# chip-to-chip interconnect over 4 links, i.e. 50e9 bytes/s per link.
+PEAKS: Dict[str, HW] = {V5E_KIND: HW(peak_flops=197e12, hbm_bw=819e9,
+                                     ici_bw=50e9)}
+
+
+def peaks(device_kind: str) -> HW:
+    """The published peaks of ``device_kind``; an unknown kind is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}") from None
 
 
 @dataclass
@@ -33,7 +47,7 @@ class RooflineTerms:
     collective_bytes_per_device: float
     model_flops_global: float        # 6*N*D (or 6*N_active*D for MoE)
     chips: int
-    hw: HW = field(default_factory=lambda: V5E)
+    hw: HW
 
     @property
     def t_compute(self) -> float:
@@ -89,24 +103,12 @@ class RooflineTerms:
         }
 
 
-def normalize_cost_analysis(cost) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` drifted across JAX versions: older
-    releases return a list with one properties-dict per program, newer ones
-    return the dict directly (and either may be None/empty).  Normalize to a
-    flat dict so callers never care."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
-
-
 def roofline_from_analysis(cost, collective_bytes_per_device: float,
                            model_flops_global: float, chips: int,
-                           hw: HW = V5E) -> RooflineTerms:
-    """``cost`` is a ``cost_analysis()`` result in any JAX flavor (dict,
-    [dict], or None) or a hand-built {'flops', 'bytes accessed'} dict."""
-    cost = normalize_cost_analysis(cost)
+                           hw: HW) -> RooflineTerms:
+    """``cost`` is a ``compiled.cost_analysis()`` dict (None where the
+    backend gives none) or a hand-built {'flops', 'bytes accessed'} dict."""
+    cost = cost or {}
     return RooflineTerms(
         flops_per_device=float(cost.get("flops", 0.0)),
         hbm_bytes_per_device=float(cost.get("bytes accessed", 0.0)),

@@ -12,8 +12,10 @@ SRC = os.path.join(REPO, "src")
 
 
 def run_helper(script: str, *args, devices: int = 8, timeout: int = 900):
-    """Run tests/helpers/<script> in a subprocess with N virtual devices."""
+    """Run tests/helpers/<script> in a subprocess with N virtual CPU
+    devices (held to the CPU: a chip belongs to one process)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run(

@@ -9,8 +9,7 @@ from jax.sharding import Mesh
 from repro.configs import smoke_config
 from repro.launch.cells import make_cell
 from repro.utils.hlo import collective_bytes
-from repro.utils.roofline import (normalize_cost_analysis,
-                                  roofline_from_analysis)
+from repro.utils.roofline import V5E_KIND, peaks, roofline_from_analysis
 
 devs = jax.devices()
 assert len(devs) == 8, len(devs)
@@ -33,10 +32,10 @@ for arch, shape in [("yi-6b", "train_4k"), ("granite-moe-3b-a800m", "train_4k"),
         lowered = cell.lower()
         compiled = lowered.compile()
         ma = compiled.memory_analysis()
-        ca = normalize_cost_analysis(compiled.cost_analysis())
+        ca = compiled.cost_analysis() or {}
         coll = collective_bytes(compiled.as_text())
         terms = roofline_from_analysis(ca, coll.get("total", 0),
-                                       cell.model_flops, 8)
+                                       cell.model_flops, 8, peaks(V5E_KIND))
         assert ma.temp_size_in_bytes >= 0
         assert ca.get("flops", 0) > 0
         assert terms.bottleneck in ("compute", "memory", "collective")

@@ -8,6 +8,7 @@ import sys
 import jax
 import numpy as np
 
+from repro.checkpoint import snapshot_to_host
 from repro.configs import smoke_config
 from repro.core.elastic import ElasticTrainer, TrainJobConfig
 
@@ -40,6 +41,11 @@ perr = max(float(np.max(np.abs(a.astype(np.float32) - b.astype(np.float32))))
 la = [x["loss"] for x in static.metrics_log]
 lb = [x["loss"] for x in elastic.metrics_log]
 lerr = max(abs(a - b) for a, b in zip(la, lb))
+
+# the fused snapshot packs a multi-device (replicated) state on one device
+fused = snapshot_to_host(elastic.params, fused=True)
+plain = snapshot_to_host(elastic.params)
+assert all(fused[k].tobytes() == plain[k].tobytes() for k in plain)
 
 print(f"PARAM_ERR {perr:.3e}")
 print(f"LOSS_ERR {lerr:.3e}")

@@ -1,0 +1,72 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e chip.
+
+Nothing runs: each case lowers and compiles at real widths for a chip that is
+described, not attached, and so catches what interpret mode cannot (block
+shapes the TPU's tiling refuses, VMEM overruns).  The topology is described
+inside a fixture, so only the worker that runs this file loads the TPU
+compiler.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+from repro.kernels.pack import pack_leaves_pallas
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache; keep it out
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    lowered = jax.jit(fn).lower(*args)
+    assert "tpu_custom_call" in lowered.as_text()
+    return lowered.compile()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_attention_compiles_at_yi6b_heads(one_chip, dtype):
+    # yi-6b: 32 query heads, 4 KV heads, head_dim 128; seq 2048
+    q = ((1, 2048, 32, 128), dtype)
+    kv = ((1, 2048, 4, 128), dtype)
+    _compile(lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
+             one_chip, q, kv, kv)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
+def test_pack_kernel_compiles(one_chip, dtype):
+    shapes = [((4096, 4096), dtype), ((4096,), dtype), ((11008, 37), dtype)]
+    _compile(lambda *leaves: pack_leaves_pallas(list(leaves)), one_chip,
+             *shapes)
+
+
+def test_rmsnorm_compiles_at_d4096(one_chip):
+    _compile(lambda x, w: ops.rmsnorm(x, w), one_chip,
+             ((4096, 4096), jnp.float32), ((4096,), jnp.float32))
+
+
+def test_ssd_compiles_at_mamba2_widths(one_chip):
+    # mamba2-1.3b: d_inner 4096 = 64 heads x head_dim 64, d_state 128,
+    # one B/C group, chunk 128
+    B, L, H, P, G, N = 1, 2048, 64, 64, 1, 128
+    _compile(lambda x, dt, a, b, c: ops.ssd(x, dt, a, b, c, chunk=128),
+             one_chip, ((B, L, H, P), jnp.float32), ((B, L, H), jnp.float32),
+             ((H,), jnp.float32), ((B, L, G, N), jnp.float32),
+             ((B, L, G, N), jnp.float32))

@@ -1,4 +1,4 @@
-"""HLO collective parser and roofline arithmetic."""
+"""HLO collective parser, roofline arithmetic, peaks and compile cache."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,7 +6,8 @@ import pytest
 
 from repro.utils.hlo import (collective_bytes, parse_hlo_collectives,
                              _shape_bytes)
-from repro.utils.roofline import HW, RooflineTerms, roofline_from_analysis
+from repro.utils.roofline import (HW, V5E_KIND, RooflineTerms, peaks,
+                                  roofline_from_analysis)
 
 
 def test_shape_bytes():
@@ -83,24 +84,50 @@ def test_roofline_terms_and_bottleneck():
 def test_roofline_from_cost_analysis_dict():
     t = roofline_from_analysis({"flops": 10.0, "bytes accessed": 20.0},
                                collective_bytes_per_device=5.0,
-                               model_flops_global=100.0, chips=4)
+                               model_flops_global=100.0, chips=4,
+                               hw=peaks(V5E_KIND))
     assert t.flops_per_device == 10.0
     assert t.hbm_bytes_per_device == 20.0
     assert t.collective_bytes_per_device == 5.0
 
 
 def test_roofline_normalizes_cost_analysis_jax_flavors():
-    """compiled.cost_analysis() drifted across JAX versions: older releases
-    return [properties-dict], newer ones the dict itself, either may be
-    None/empty — all four shapes must work (the list flavor is the seed
-    failure behind test_dryrun_machinery_small_mesh)."""
-    from repro.utils.roofline import normalize_cost_analysis
+    """``compiled.cost_analysis()`` gives a dict, or None where the backend
+    has no estimate; both must work."""
     d = {"flops": 10.0, "bytes accessed": 20.0}
-    assert normalize_cost_analysis(d) == d
-    assert normalize_cost_analysis([d]) == d
-    assert normalize_cost_analysis(None) == {}
-    assert normalize_cost_analysis([]) == {}
-    t = roofline_from_analysis([d], collective_bytes_per_device=5.0,
-                               model_flops_global=100.0, chips=4)
+    hw = peaks(V5E_KIND)
+    t = roofline_from_analysis(d, collective_bytes_per_device=5.0,
+                               model_flops_global=100.0, chips=4, hw=hw)
     assert t.flops_per_device == 10.0
     assert t.hbm_bytes_per_device == 20.0
+    t = roofline_from_analysis(None, collective_bytes_per_device=5.0,
+                               model_flops_global=100.0, chips=4, hw=hw)
+    assert t.flops_per_device == 0.0
+    f = jax.jit(lambda x: x @ x).lower(
+        jax.ShapeDtypeStruct((128, 128), jnp.float32)).compile()
+    assert roofline_from_analysis(f.cost_analysis(), 0.0, 1.0, 1,
+                                  hw).flops_per_device > 0
+
+
+def test_peaks_are_keyed_by_device_kind():
+    assert peaks("TPU v5 lite").peak_flops == 197e12
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")
+
+
+def test_compile_cache_honours_env_else_repo_path(monkeypatch, tmp_path):
+    import os
+
+    from repro.utils.compile_cache import CACHE_DIR, enable_compile_cache
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert CACHE_DIR == os.path.join(repo, ".jax_cache")
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev   # JAX's own
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

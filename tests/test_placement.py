@@ -311,6 +311,7 @@ class _StubTrainer:
         self.total_steps = total_steps
         self.step_idx = 0
         self.devices_history = []
+        self.closed = False
 
     @property
     def done(self):
@@ -324,12 +325,35 @@ class _StubTrainer:
         self.devices_history.append(tuple(devices))
         return RescaleTimings()
 
+    def close(self):
+        self.closed = True
+
 
 def _controller(**kw):
     kw.setdefault("slots", 8)
     kw.setdefault("slots_per_node", 4)
     kw.setdefault("policy", PolicyConfig(rescale_gap=0.0))
     return ElasticClusterController(list(range(8)), **kw)
+
+
+def test_operator_releases_trainer_on_completion():
+    # one slot, two jobs: the second starts only once the first completes,
+    # and by then the first must hold no trainer (its device state is freed)
+    made = []
+
+    def factory(devices):
+        made.append(_StubTrainer(3))
+        return made[-1]
+
+    op = _controller(slots=1, slots_per_node=None)
+    op.submit(JobSpec("a", 1, 1, 1, 0.0), factory)
+    op.submit(JobSpec("b", 1, 1, 1, 0.0), factory)
+    op.run()
+    assert [j.status for j in op.cluster.jobs.values()] == \
+        [JobStatus.COMPLETED] * 2
+    assert all(live.trainer is None for live in op.live.values())
+    assert [t.closed for t in made] == [True, True]
+    assert [t.step_idx for t in made] == [3, 3]
 
 
 def test_operator_partitions_devices_into_nodes():
