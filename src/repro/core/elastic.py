@@ -20,7 +20,6 @@ static run's loss trajectory (pinned by tests).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
@@ -36,6 +35,7 @@ from repro.checkpoint import (AsyncCheckpointer, MemoryCheckpointStore,
 from repro.configs.base import ModelConfig
 from repro.data import make_stream
 from repro.models import model as M
+from repro.obs import live
 from repro.optim import (AdamWConfig, adamw_init, adamw_update, opt_logical_axes,
                          warmup_cosine)
 from repro.sharding import AxisRules, RULE_SETS, axis_rules, make_param_shardings
@@ -90,21 +90,23 @@ class ElasticTrainer:
             total_steps=job.total_steps)
 
         # initial "restart" (mesh + compile) and state init
-        t0 = time.perf_counter()
-        self._mesh_cache: Dict[tuple, dict] = {}
-        self._async_ckpt: Optional[AsyncCheckpointer] = None
-        self.validate_devices(devices)
-        self._ensure_mesh(devices)
-        key = jax.random.PRNGKey(job.seed)
-        with axis_rules(self.rules):
-            self.params = jax.jit(
-                lambda k: M.init_params(self.cfg, k),
-                out_shardings=self._param_sh)(key)
-            self.opt_state = jax.jit(
-                adamw_init, out_shardings=self._opt_sh)(self.params)
-        self._compile()
-        self._mesh_cache[self._mesh_key(devices)]["compiled"] = self._compiled
-        self.startup_time = time.perf_counter() - t0
+        with live.span("trainer.build") as build:
+            self._mesh_cache: Dict[tuple, dict] = {}
+            self._async_ckpt: Optional[AsyncCheckpointer] = None
+            self.validate_devices(devices)
+            self._ensure_mesh(devices)
+            key = jax.random.PRNGKey(job.seed)
+            with live.span("trainer.init"), axis_rules(self.rules):
+                self.params = jax.jit(
+                    lambda k: M.init_params(self.cfg, k),
+                    out_shardings=self._param_sh)(key)
+                self.opt_state = jax.jit(
+                    adamw_init, out_shardings=self._opt_sh)(self.params)
+                jax.block_until_ready((self.params, self.opt_state))
+            self._compile()
+            self._mesh_cache[self._mesh_key(devices)]["compiled"] = \
+                self._compiled
+        self.startup_time = build.record.seconds
 
     # -- mesh / sharding ------------------------------------------------------
     @property
@@ -201,7 +203,7 @@ class ElasticTrainer:
 
     def _compile(self):
         """The 'restart' stage: jit + AOT compile for the current mesh."""
-        with axis_rules(self.rules):
+        with live.span("trainer.compile"), axis_rules(self.rules):
             jitted = jax.jit(
                 self._step_fn,
                 in_shardings=(self._param_sh, self._opt_sh, self._batch_sh,
@@ -217,15 +219,27 @@ class ElasticTrainer:
 
     # -- public API -------------------------------------------------------------
     def step(self) -> dict:
-        batch_np = self.stream.global_batch_at(self.step_idx)
-        batch = {k: jax.device_put(v, self._batch_sh[k])
-                 for k, v in batch_np.items()}
-        step_arr = jax.device_put(jnp.asarray(self.step_idx, jnp.int32),
-                                  self._scalar_sh)
-        self.params, self.opt_state, metrics = self._compiled(
-            self.params, self.opt_state, batch, step_arr)
+        """One training step.  Its spans, all carrying the step index:
+        ``trainer.step`` around ``trainer.batch`` (the host draw),
+        ``trainer.put`` (the ``device_put``s), ``trainer.dispatch`` (the call
+        into the compiled step), ``trainer.wait`` (until its outputs are
+        ready) and ``trainer.readback`` (the metrics to Python floats)."""
+        with live.step_span("trainer.step", self.step_idx):
+            with live.span("trainer.batch"):
+                batch_np = self.stream.global_batch_at(self.step_idx)
+            with live.span("trainer.put"):
+                batch = {k: jax.device_put(v, self._batch_sh[k])
+                         for k, v in batch_np.items()}
+                step_arr = jax.device_put(
+                    jnp.asarray(self.step_idx, jnp.int32), self._scalar_sh)
+            with live.span("trainer.dispatch"):
+                self.params, self.opt_state, metrics = self._compiled(
+                    self.params, self.opt_state, batch, step_arr)
+            with live.span("trainer.wait"):
+                jax.block_until_ready(metrics)
+            with live.span("trainer.readback"):
+                metrics = {k: float(v) for k, v in metrics.items()}
         self.step_idx += 1
-        metrics = {k: float(v) for k, v in metrics.items()}
         metrics["step"] = self.step_idx
         metrics["replicas"] = self.replicas
         self.metrics_log.append(metrics)
@@ -256,38 +270,40 @@ class ElasticTrainer:
             via_host = surviving_devices(self.devices, devices) == 0
         t = RescaleTimings(path="host" if via_host else "p2p")
 
-        t0 = time.perf_counter()
-        # load balance: re-split the data stream over the new replica count
-        new_r = len(devices) // self.job.model_axis
-        bounds = [self.stream.shard_bounds(i, new_r) for i in range(new_r)]
-        t.load_balance = time.perf_counter() - t0
+        with live.span("elastic.rescale", self.step_idx):
+            # load balance: re-split the data stream over the new replicas
+            with live.span("elastic.load_balance") as sp:
+                new_r = len(devices) // self.job.model_axis
+                bounds = [self.stream.shard_bounds(i, new_r)
+                          for i in range(new_r)]
+            t.load_balance = sp.record.seconds
 
-        host = None
-        if via_host:
-            t0 = time.perf_counter()
-            host = {"params": snapshot_to_host(self.params),
-                    "opt": snapshot_to_host(self.opt_state)}
-            t.checkpoint = time.perf_counter() - t0
+            host = None
+            if via_host:
+                with live.span("elastic.checkpoint") as sp:
+                    host = {"params": snapshot_to_host(self.params),
+                            "opt": snapshot_to_host(self.opt_state)}
+                t.checkpoint = sp.record.seconds
 
-        old_params, old_opt = self.params, self.opt_state
-        t0 = time.perf_counter()
-        if not self._ensure_mesh(devices):
-            self._compile()
-            self._mesh_cache[self._mesh_key(devices)]["compiled"] = \
-                self._compiled
-        t.restart = time.perf_counter() - t0
+            old_params, old_opt = self.params, self.opt_state
+            with live.span("elastic.restart") as sp:
+                if not self._ensure_mesh(devices):
+                    self._compile()
+                    self._mesh_cache[self._mesh_key(devices)]["compiled"] = \
+                        self._compiled
+            t.restart = sp.record.seconds
 
-        t0 = time.perf_counter()
-        if via_host:
-            self.params = restore_from_host(host["params"], old_params,
-                                            self._param_sh)
-            self.opt_state = restore_from_host(host["opt"], old_opt,
-                                               self._opt_sh)
-        else:
-            self.params = device_reshard(old_params, self._param_sh)
-            self.opt_state = device_reshard(old_opt, self._opt_sh)
-        jax.block_until_ready((self.params, self.opt_state))
-        t.restore = time.perf_counter() - t0
+            with live.span("elastic.restore") as sp:
+                if via_host:
+                    self.params = restore_from_host(host["params"], old_params,
+                                                    self._param_sh)
+                    self.opt_state = restore_from_host(host["opt"], old_opt,
+                                                       self._opt_sh)
+                else:
+                    self.params = device_reshard(old_params, self._param_sh)
+                    self.opt_state = device_reshard(old_opt, self._opt_sh)
+                jax.block_until_ready((self.params, self.opt_state))
+            t.restore = sp.record.seconds
 
         self.rescale_log.append(t)
         del bounds

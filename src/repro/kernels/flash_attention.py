@@ -100,6 +100,7 @@ def _flash_fwd(q, k, v, *, causal: bool, scale: Optional[float],
                                block_k=block_k, nk=nk, causal=causal)
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),

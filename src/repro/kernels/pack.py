@@ -77,6 +77,7 @@ def _pack_call(*leaves: jax.Array, block_rows: int, lane: int,
     kernel = functools.partial(_pack_kernel, starts=starts, nblocks=nblocks)
     return pl.pallas_call(
         kernel,
+        name="pack_leaves",
         grid=(total,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, lane), lambda g: (g, 0)),

@@ -49,6 +49,7 @@ def _rmsnorm_call(x, w, *, eps: float, interpret: bool):
     kernel = functools.partial(_rmsnorm_kernel, eps=eps)
     out = pl.pallas_call(
         kernel,
+        name="rmsnorm",
         grid=(n,),
         in_specs=[pl.BlockSpec((block_rows, D), lambda i: (i, 0)),
                   pl.BlockSpec((D,), lambda i: (0,))],

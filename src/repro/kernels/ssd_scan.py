@@ -99,6 +99,7 @@ def _ssd_call(x, dt, a_log, b, c, *, chunk: int, interpret: bool):
     ct = c.transpose(0, 2, 1, 3)
     y = pl.pallas_call(
         _ssd_kernel,
+        name="ssd_scan",
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

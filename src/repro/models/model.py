@@ -38,6 +38,7 @@ LOSS_CHUNK = 512
 # Embedding / head
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def _embed(cfg: ModelConfig, params, tokens):
     x = params["embed"][tokens]
     return shard_constraint(x, "batch", "seq", "embed")
@@ -67,17 +68,19 @@ def forward_hidden(cfg: ModelConfig, params, batch, *, mode: str = "train"):
     x = _embed(cfg, params, tokens)
     x, _, aux = tfm.decoder(cfg, params["decoder"], x, positions=positions,
                             mode=mode, cache=None, pos=None, enc_out=enc_out)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("norm"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return x, aux
 
 
 def loss_fn(cfg: ModelConfig, params, batch) -> Tuple[jax.Array, dict]:
     hidden, aux = forward_hidden(cfg, params, batch, mode="train")
     w_head = _head_weight(cfg, params)
-    loss_sum, weight = chunked_softmax_xent(
-        hidden, w_head, batch["labels"],
-        chunk=min(LOSS_CHUNK, hidden.shape[1]),
-        valid_vocab=cfg.vocab_size)
+    with jax.named_scope("head"):
+        loss_sum, weight = chunked_softmax_xent(
+            hidden, w_head, batch["labels"],
+            chunk=min(LOSS_CHUNK, hidden.shape[1]),
+            valid_vocab=cfg.vocab_size)
     xent = loss_sum / jnp.maximum(weight, 1.0)
     loss = xent + aux
     return loss, {"loss": loss, "xent": xent, "aux": aux, "tokens": weight}

@@ -59,28 +59,31 @@ def apply_layer(cfg: ModelConfig, p: dict, x, layer_idx: int, *, positions,
     new_cache = {}
     c_in = cache or {}
 
-    h = rmsnorm(x, p["mixer_norm"], cfg.norm_eps)
-    if mixer == ATTN:
-        y, kvc = attn_mod.attn_forward(
-            cfg, p["mixer"], h, positions=positions, mode=mode,
-            cache=c_in.get("kv"), pos=pos, causal=True)
-        new_cache["kv"] = kvc
-    elif mixer == MLA:
-        y, kvc = attn_mod.mla_forward(
-            cfg, p["mixer"], h, positions=positions, mode=mode,
-            cache=c_in.get("kv"), pos=pos, absorb=_MLA_ABSORB[mode])
-        new_cache["kv"] = kvc
-    elif mixer == SSM:
-        y, sc = ssm_mod.ssm_forward(cfg, p["mixer"], h, mode=mode,
-                                    cache=c_in.get("ssm"))
-        new_cache["ssm"] = sc
-    else:
-        raise ValueError(mixer)
+    with jax.named_scope("norm"):
+        h = rmsnorm(x, p["mixer_norm"], cfg.norm_eps)
+    with jax.named_scope("mixer"):
+        if mixer == ATTN:
+            y, kvc = attn_mod.attn_forward(
+                cfg, p["mixer"], h, positions=positions, mode=mode,
+                cache=c_in.get("kv"), pos=pos, causal=True)
+            new_cache["kv"] = kvc
+        elif mixer == MLA:
+            y, kvc = attn_mod.mla_forward(
+                cfg, p["mixer"], h, positions=positions, mode=mode,
+                cache=c_in.get("kv"), pos=pos, absorb=_MLA_ABSORB[mode])
+            new_cache["kv"] = kvc
+        elif mixer == SSM:
+            y, sc = ssm_mod.ssm_forward(cfg, p["mixer"], h, mode=mode,
+                                        cache=c_in.get("ssm"))
+            new_cache["ssm"] = sc
+        else:
+            raise ValueError(mixer)
     x = x + y
     x = shard_constraint(x, "batch", "seq", "embed")
 
     if "cross" in p:
-        h = rmsnorm(x, p["cross_norm"], cfg.norm_eps)
+        with jax.named_scope("norm"):
+            h = rmsnorm(x, p["cross_norm"], cfg.norm_eps)
         if mode == "decode":
             ck = c_in["cross"]
             kv = (ck["ck"], ck["cv"])
@@ -98,12 +101,15 @@ def apply_layer(cfg: ModelConfig, p: dict, x, layer_idx: int, *, positions,
         x = shard_constraint(x, "batch", "seq", "embed")
 
     if ff != FF_NONE:
-        h = rmsnorm(x, p["ff_norm"], cfg.norm_eps)
+        with jax.named_scope("norm"):
+            h = rmsnorm(x, p["ff_norm"], cfg.norm_eps)
         if ff == FF_MOE:
-            y, aux = moe_mod.moe_forward(cfg, p["ff"], h)
+            with jax.named_scope("experts"):
+                y, aux = moe_mod.moe_forward(cfg, p["ff"], h)
         else:
             from repro.models.layers import apply_ffn
-            y = apply_ffn(p["ff"], h, ff)
+            with jax.named_scope("ffn"):
+                y = apply_ffn(p["ff"], h, ff)
         x = x + y
         x = shard_constraint(x, "batch", "seq", "embed")
 

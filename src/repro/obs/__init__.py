@@ -10,6 +10,8 @@
 - :mod:`repro.obs.critical_path` per-job phase decomposition + fleet rollups
 - :mod:`repro.obs.profile`   zero-dep self-profiler for the simulator hot path
 - :mod:`repro.obs.watchdog`  perf baseline diff + metric-stream anomaly scan
+- :mod:`repro.obs.live`      spans and counters of the live JAX trainer, on
+  the profiler's clock (imports JAX, so it is not imported here)
 """
 from repro.obs.critical_path import (PHASES, FleetPhases, PhaseLedger,
                                      decompose, rollup)

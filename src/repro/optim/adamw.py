@@ -55,8 +55,10 @@ def global_norm(tree) -> jax.Array:
     return jnp.sqrt(sq)
 
 
+@jax.named_scope("optimizer")
 def adamw_update(cfg: AdamWConfig, grads, state, params, lr):
-    """Returns (new_params, new_state, metrics)."""
+    """Returns (new_params, new_state, metrics); the global-norm clip is
+    inside the ``optimizer`` scope too."""
     count = state["count"] + 1
     gnorm = global_norm(grads)
     scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9))
