@@ -45,13 +45,14 @@ PEAK_LR = 3e-4
 # and the final norm gives unit-RMS hidden states, so logits are ~N(0, 1) and
 # the expected first cross-entropy is ln(vocab) + 1/2.
 LOSS0_BOUND = 1.0
-# The Pallas and XLA attention paths differ only in rounding: matmul pass
-# count and the order of the online softmax, at most ~2^-8 relative per
-# element.  Against the f32 reference the kernel stays within 2% of the
-# output's largest magnitude; a wrong mask, head mapping or scale is off by
-# O(1).  In the loss that rounding averages over all 8192 tokens.
+# The Pallas and XLA attention paths differ only in rounding: both run their
+# matmuls at the default precision and differ in the order of the online
+# softmax, at most ~2^-8 relative per element.  Output and gradients stay
+# within 2% of the largest magnitude of the other path's (or of the f32
+# reference's); a wrong mask, head mapping or scale is off by O(1).
 FLASH_REL_TOL = 2e-2
-PALLAS_LOSS_TOL = 1e-2
+FLASH_KERNELS = ("flash_attention_fwd", "flash_attention_dkv",
+                 "flash_attention_dq")
 # Rescaled and static runs differ only in the order of the cross-replica
 # sums (f32).
 RESCALE_LOSS_TOL = 1e-3
@@ -123,50 +124,57 @@ def phase_train(cfg, job, dev, steps: int = 4):
     print(f"(b) losses {losses}")
     print(f"(b) step seconds: warm-up {secs[0]}, then {secs[1:]}")
     print(f"(b) peak_bytes_in_use {dev.memory_stats()['peak_bytes_in_use']}")
+    text = tr.compiled_step.as_text()
+    check(all(name in text for name in FLASH_KERNELS),
+          f"train step holds the Pallas attention kernels {FLASH_KERNELS}")
     print("(b) training: PASS")
-    return losses
 
 
 # -- (c) Pallas attention -----------------------------------------------------
 
-def phase_pallas(cfg, job, dev, xla_loss0: float):
+def phase_pallas(cfg, dev):
     import jax
     import jax.numpy as jnp
 
-    from repro.core.elastic import ElasticTrainer
     from repro.kernels import ops, ref
+    from repro.kernels.blocked import blocked_attention
 
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    ks = jax.random.split(jax.random.PRNGKey(SEED), 3)
-    q = jax.device_put(jax.random.normal(ks[0], (1, job.seq_len, H, hd)), dev)
-    k = jax.device_put(jax.random.normal(ks[1], (1, job.seq_len, KV, hd)), dev)
-    v = jax.device_put(jax.random.normal(ks[2], (1, job.seq_len, KV, hd)), dev)
-    flash = jax.jit(lambda q, k, v: ops.flash_attention(q, k, v, causal=True))
-    check(lowered_has_kernel(flash.lower(q, k, v).as_text()),
-          "flash attention lowers to the Pallas kernel")
-    out = flash(q, k, v)
-    with jax.default_matmul_precision("highest"):
-        want = ref.flash_attention_ref(q, k, v, causal=True)
-    err = float(jnp.max(jnp.abs(out - want)))
-    scale = float(jnp.max(jnp.abs(want)))
-    check(err <= FLASH_REL_TOL * scale,
-          f"flash max error {err} within {FLASH_REL_TOL} x {scale}")
-    print(f"(c) flash kernel (1, {job.seq_len}, {H}/{KV} heads, {hd}) vs "
-          f"f32 reference: max abs error {err}, max |ref| {scale}")
+    scale = hd ** -0.5
+    ks = jax.random.split(jax.random.PRNGKey(SEED), 4)
+    q, w = (jax.device_put(jax.random.normal(key, (1, SEQ_LEN, H, hd)), dev)
+            for key in ks[:2])
+    k, v = (jax.device_put(jax.random.normal(key, (1, SEQ_LEN, KV, hd)), dev)
+            for key in ks[2:])
 
-    ops.set_pallas(True)
-    try:
-        tr = ElasticTrainer(cfg, job, [dev])
-    finally:
-        ops.set_pallas(False)
-    check(lowered_has_kernel(tr.compiled_step.as_text()),
-          "train step holds the Pallas kernel")
-    (loss,), (secs,) = timed_steps(tr, 1)
-    check(abs(loss - xla_loss0) <= PALLAS_LOSS_TOL,
-          f"Pallas loss {loss} within {PALLAS_LOSS_TOL} of XLA {xla_loss0}")
-    print(f"(c) first loss: Pallas {loss}, XLA blocked {xla_loss0}, "
-          f"difference {loss - xla_loss0} (tolerance {PALLAS_LOSS_TOL}); "
-          f"step {secs} s after a {tr.startup_time} s build")
+    def flash(q, k, v):
+        return ops.flash_attention(q, k, v, scale=scale)
+
+    def blocked(q, k, v):
+        return blocked_attention(q, k, v, True, scale)
+
+    def grads(att):
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(att(q, k, v) * w),
+                                (0, 1, 2)))
+
+    text = grads(flash).lower(q, k, v).as_text()
+    check(all(name in text for name in FLASH_KERNELS),
+          f"flash attention and its gradient lower to {FLASH_KERNELS}")
+    with jax.default_matmul_precision("highest"):
+        want = ref.flash_attention_ref(q, k, v, causal=True, scale=scale)
+    out = jax.jit(flash)(q, k, v)
+    pairs = [("out", "blocked", out, jax.jit(blocked)(q, k, v)),
+             ("out", "the f32 reference", out, want)]
+    pairs += [(f"d{n}", "blocked", a, b) for n, a, b in
+              zip("qkv", grads(flash)(q, k, v), grads(blocked)(q, k, v))]
+    for what, against, got, other in pairs:
+        err = float(jnp.max(jnp.abs(got - other)))
+        mag = float(jnp.max(jnp.abs(other)))
+        check(err <= FLASH_REL_TOL * mag,
+              f"flash {what} against {against}: max error {err} within "
+              f"{FLASH_REL_TOL} x {mag}")
+        print(f"(c) flash {what} (1, {SEQ_LEN}, {H}/{KV} heads, {hd}) "
+              f"against {against}: max abs error {err}, max magnitude {mag}")
     print("(c) Pallas attention: PASS")
 
 
@@ -365,9 +373,9 @@ def main() -> None:
     if args.chips == 4:
         phase_rescale(cfg, job_config(total_steps=8), devs[:4])
     else:
-        losses = phase_train(cfg, job_config(total_steps=8), dev)
+        phase_train(cfg, job_config(total_steps=8), dev)
         gc.collect()
-        phase_pallas(cfg, job_config(total_steps=8), dev, losses[0])
+        phase_pallas(cfg, dev)
         gc.collect()
         root = tempfile.mkdtemp(prefix="chip_smoke_ckpt_")
         try:

@@ -1,11 +1,15 @@
 """Blocked (flash-style) attention in pure jnp — the XLA-lowerable twin of
 ``kernels/flash_attention.py``.
 
-Used whenever the Pallas kernel can't run (CPU container, and the multi-pod
-dry-run, which lowers on the CPU backend): a ``lax.scan`` over KV blocks with
-online softmax keeps the live working set at one (B,KV,G,Sq,block_k) tile
-instead of the full O(Sq x Sk) score matrix (2.1 GB/device/tensor on
-yi-6b train_4k — see EXPERIMENTS.md §Perf iteration 1).
+Used wherever the Pallas kernels do not run: off a TPU (the CPU tests, the
+multi-pod dry-run, which lowers on the CPU backend), on meshes of more than
+one device, and for the train and prefill calls the kernels do not take
+(non-causal or cross-attention, MLA's unequal q/v head sizes, heads or
+lengths not lane-aligned; ``models/attention.py`` ``_use_flash``).  A
+``lax.scan`` over KV blocks with online softmax keeps the live working set
+at one (B,KV,G,Sq,block_k) tile instead of the full O(Sq x Sk) score matrix
+(2.1 GB/device/tensor on yi-6b train_4k — see EXPERIMENTS.md §Perf
+iteration 1).
 
 The backward pass is the standard flash recomputation: only (out, lse) are
 saved; dq/dk/dv are accumulated in a second scan over KV blocks.  FLOPs ~2x

@@ -1,13 +1,33 @@
-"""Causal GQA flash attention — Pallas TPU kernel.
+"""Causal GQA flash attention — Pallas TPU forward and backward kernels.
 
-TPU-native design (not a CUDA port): the grid is (batch, q_head, q_block,
-k_block) with the k dimension innermost and *revisiting* the same output
-block, so the online-softmax accumulators live in VMEM scratch across k steps.
-Tiles are MXU-aligned (block_q x head_dim and block_k x head_dim, both 128 by
-default).  Causal q-blocks skip k-blocks entirely above the diagonal.
+Layout: the kernels read the model's (B, S, H, hd) q, o, dO, dq feature-major,
+as (B, H, hd, S), and k, v, dk, dv as (B, KV, hd, S).  That is the physical
+layout XLA gives the attention projections' outputs on a TPU (sequence
+minor), so the transposes around the kernels are bitcasts, not copies.  A
+head's block is a (hd, rows) tile: queries and keys lie on lanes, and every
+softmax statistic is a (1, block_q) row, so no kernel reduces across lanes.
 
-GQA is handled in the k/v index maps (q head h reads kv head h // group_size),
-so repeated KV is never materialized in HBM or VMEM.
+One grid step serves the G = H/KV query heads of one KV head: the q block
+is heads [j*G, (j+1)*G) and the k/v block KV head j.  GQA is thus in the
+index maps; repeated KV is never materialised, and each K/V block is
+fetched once for G heads.
+
+- ``flash_attention_fwd``, grid (B, KV, nq, nk), k innermost and revisiting
+  the output block: s^T = k q^T, an online softmax over sublanes with m, l
+  and o^T = v^T p^T in VMEM scratch.  Returns o and the f32 row
+  log-sum-exp ``lse``, (B, H, 1, S).
+- ``flash_attention_dkv``, grid (B, KV, nk, nq), FlashAttention-2's
+  backward: p^T = exp(s^T - lse), ds^T = p^T (v dO^T - delta),
+  dv^T += dO^T p, dk^T += q^T ds.
+- ``flash_attention_dq``, grid (B, KV, nq, nk): the same p^T and ds^T,
+  dq^T += k^T ds^T.
+
+Both backward kernels recompute p from ``lse`` in VMEM and take
+``delta = rowsum(dO * o)`` in f32.  Causal: a block wholly above the
+diagonal is neither computed nor fetched (its index map is clamped to a
+block the step next to it holds, which Pallas does not fetch again); only
+blocks that cross the diagonal are masked.  Matmuls run at the default
+precision; softmax statistics and accumulators are f32.
 """
 from __future__ import annotations
 
@@ -22,13 +42,94 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.platform import on_platform
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
+# (block_q, block_k) of all three kernels, from a sweep on one v5e chip at
+# (4, 2048, 32/4, 128) in f32 (recorded in PERF.md)
+BLOCKS = (512, 512)
+_LANES = 128
+_VMEM_LIMIT = 64 * 2**20
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                  scale: float, block_q: int, block_k: int, nk: int,
-                  causal: bool):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
+def _block(S: int, preferred: int, given: Optional[int]) -> int:
+    """``given``, else the largest of preferred, preferred/2, ... that
+    divides S (S itself where S is shorter)."""
+    if given is not None:
+        return min(given, S)
+    b = min(preferred, S)
+    while S % b:
+        b //= 2
+    return b
+
+
+def _block_sizes(S: int, block_q: Optional[int], block_k: Optional[int]):
+    return _block(S, BLOCKS[0], block_q), _block(S, BLOCKS[1], block_k)
+
+
+def fits(S: int, hd: int) -> bool:
+    """Whether the kernels take sequence length ``S`` and head size ``hd``:
+    lane-aligned heads and rows (every block size then divides S)."""
+    return hd % _LANES == 0 and S % _LANES == 0
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _tile(ref, g):
+    """Head ``g``'s (hd, rows) tile of a block, as f32."""
+    return ref[0, g].astype(jnp.float32)
+
+
+def _by_causal_block(body, q_start, bq, k_start, bk):
+    """Run ``body(masked)`` for a (q block, k block) pair: unmasked below
+    the diagonal, masked across it, not at all above it."""
+    below = k_start + bk - 1 <= q_start
+    crosses = jnp.logical_and(k_start <= q_start + bq - 1,
+                              jnp.logical_not(below))
+    pl.when(below)(lambda: body(False))
+    pl.when(crosses)(lambda: body(True))
+
+
+def _keep(q_start, k_start, bk, bq):
+    """Causal mask of a (keys, queries) tile."""
+    kp = k_start + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    qp = q_start + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+    return qp >= kp
+
+
+def _k_block(bq, bk):
+    """k block ``t`` of q block ``i``, held at the last one it needs."""
+    return lambda i, t: jnp.minimum(t, (i * bq + bq - 1) // bk)
+
+
+def _q_block(bq, bk):
+    """q block ``t`` of k block ``i``, held at the first one it needs."""
+    return lambda i, t: jnp.maximum(t, (i * bk) // bq)
+
+
+def _feature_major(x):
+    """(B, S, N, hd) -> (B, N, hd, S)."""
+    return x.transpose(0, 2, 3, 1)
+
+
+def _from_feature_major(x):
+    return x.transpose(0, 3, 1, 2)
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_VMEM_LIMIT)
+
+
+# ---------------------------------------------------------------------------
+# forward
+# ---------------------------------------------------------------------------
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, scale, G, bq, bk, nk):
+    iq, ik = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ik == 0)
     def _init():
@@ -36,87 +137,215 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q_start = iq * block_q
-    k_start = ik * block_k
-    # causal: the whole k-block is masked iff k_start > q_end
-    run = (k_start <= q_start + block_q - 1) if causal else (ik >= 0)
+    q_start, k_start = iq * bq, ik * bk
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, hd)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (bk, hd)
-        v = v_ref[0, 0].astype(jnp.float32)                  # (bk, hd)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (bq,bk)
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        m_prev = m_ref[...]                                   # (bq, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                                # (bq, bk)
-        alpha = jnp.exp(m_prev - m_new)                       # (bq, 1)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+    def body(masked):
+        k = _tile(k_ref, 0).T                                 # (bk, hd)
+        vt = _tile(v_ref, 0)                                  # (hd, bk)
+        keep = _keep(q_start, k_start, bk, bq) if masked else None
+        for g in range(G):
+            st = _dot(k, _tile(q_ref, g) * scale, _NN)        # (bk, bq)
+            if masked:
+                st = jnp.where(keep, st, NEG_INF)
+            m_prev = m_ref[g]                                 # (1, bq)
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=0, keepdims=True))
+            pt = jnp.exp(st - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[g] = alpha * l_ref[g] + jnp.sum(pt, axis=0, keepdims=True)
+            acc_ref[g] = acc_ref[g] * alpha + _dot(vt, pt, _NN)  # (hd, bq)
+            m_ref[g] = m_new
+
+    _by_causal_block(body, q_start, bq, k_start, bk)
 
     @pl.when(ik == nk - 1)
     def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        for g in range(G):
+            l = l_ref[g]
+            o_ref[0, g] = (acc_ref[g] / l).astype(o_ref.dtype)
+            lse_ref[0, g] = m_ref[g] + jnp.log(l)
 
 
-def flash_attention_fwd(q, k, v, *, causal: bool = True, scale: float = None,
-                        block_q: int = 128, block_k: int = 128,
-                        interpret: Optional[bool] = None):
-    """q: (B,S,H,hd); k,v: (B,S,KV,hd) -> (B,S,H,hd)."""
-    return on_platform(
-        functools.partial(_flash_fwd, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k),
-        q, k, v, interpret=interpret)
-
-
-def _flash_fwd(q, k, v, *, causal: bool, scale: Optional[float],
-               block_q: int, block_k: int, interpret: bool):
+def _fwd(q, k, v, *, scale, block_q, block_k, interpret):
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
-    scale = hd ** -0.5 if scale is None else scale
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
-    nq, nk = S // block_q, S // block_k
-
-    qt = q.transpose(0, 2, 1, 3)         # (B,H,S,hd)
-    kt = k.transpose(0, 2, 1, 3)         # (B,KV,S,hd)
-    vt = v.transpose(0, 2, 1, 3)
-
-    kernel = functools.partial(_flash_kernel, scale=scale, block_q=block_q,
-                               block_k=block_k, nk=nk, causal=causal)
-    out = pl.pallas_call(
+    bq, bk = _block_sizes(S, block_q, block_k)
+    assert fits(S, hd) and S % bq == 0 and S % bk == 0, (S, hd, bq, bk)
+    nq, nk = S // bq, S // bk
+    kernel = functools.partial(_fwd_kernel, scale=scale, G=G, bq=bq, bk=bk,
+                               nk=nk)
+    kb = _k_block(bq, bk)
+    q_spec = pl.BlockSpec((1, G, hd, bq), lambda b, j, i, t: (b, j, 0, i))
+    kv_spec = pl.BlockSpec((1, 1, hd, bk),
+                           lambda b, j, i, t: (b, j, 0, kb(i, t)))
+    o, lse = pl.pallas_call(
         kernel,
-        name="flash_attention",
-        grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, j: (b, h // G, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, i, j: (b, h // G, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        name="flash_attention_fwd",
+        grid=(B, KV, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec,
+                   pl.BlockSpec((1, G, 1, bq),
+                                lambda b, j, i, t: (b, j, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct((B, H, hd, S), q.dtype),
+                   jax.ShapeDtypeStruct((B, H, 1, S), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((G, hd, bq), jnp.float32),
+                        pltpu.VMEM((G, 1, bq), jnp.float32),
+                        pltpu.VMEM((G, 1, bq), jnp.float32)],
+        compiler_params=_params(),
         interpret=interpret,
-    )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    )(_feature_major(q), _feature_major(k), _feature_major(v))
+    return _from_feature_major(o), lse
+
+
+def flash_attention_fwd(q, k, v, *, scale: float = None,
+                        block_q: Optional[int] = None,
+                        block_k: Optional[int] = None,
+                        interpret: Optional[bool] = None):
+    """q: (B,S,H,hd); k,v: (B,S,KV,hd) -> (o (B,S,H,hd), lse (B,H,1,S) f32)."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    return on_platform(
+        functools.partial(_fwd, scale=scale, block_q=block_q,
+                          block_k=block_k),
+        q, k, v, interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# backward
+# ---------------------------------------------------------------------------
+
+def _p_ds(k, v, qt, dot_, lse, delta, keep):
+    """p^T and ds^T of one head, (keys, queries) tiles, from k, v (bk, hd)
+    and the head's q^T (scaled) and dO^T (hd, bq)."""
+    st = _dot(k, qt, _NN)
+    if keep is not None:
+        st = jnp.where(keep, st, NEG_INF)
+    pt = jnp.exp(st - lse)
+    return pt, pt * (_dot(v, dot_, _NN) - delta)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                dv_ref, dk_acc, dv_acc, *, scale, G, bq, bk, nq):
+    ik, iq = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(iq == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    q_start, k_start = iq * bq, ik * bk
+
+    def body(masked):
+        k, v = _tile(k_ref, 0).T, _tile(v_ref, 0).T           # (bk, hd)
+        keep = _keep(q_start, k_start, bk, bq) if masked else None
+        dkt, dvt = dk_acc[...], dv_acc[...]                   # (hd, bk)
+        for g in range(G):
+            qt, dot_ = _tile(q_ref, g) * scale, _tile(do_ref, g)
+            pt, dst = _p_ds(k, v, qt, dot_, lse_ref[0, g], delta_ref[0, g],
+                            keep)
+            dvt = dvt + _dot(dot_, pt, _NT)
+            dkt = dkt + _dot(qt, dst, _NT)
+        dk_acc[...] = dkt
+        dv_acc[...] = dvt
+
+    # the q blocks wholly above this k block come first in t; they hold
+    # the first needed block (the clamp) and skip
+    _by_causal_block(body, q_start, bq, k_start, bk)
+
+    @pl.when(iq == nq - 1)
+    def _finish():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+               dq_acc, *, scale, G, bq, bk, nk):
+    iq, ik = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(ik == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    q_start, k_start = iq * bq, ik * bk
+
+    def body(masked):
+        kt = _tile(k_ref, 0)                                  # (hd, bk)
+        k, v = kt.T, _tile(v_ref, 0).T                        # (bk, hd)
+        keep = _keep(q_start, k_start, bk, bq) if masked else None
+        for g in range(G):
+            _, dst = _p_ds(k, v, _tile(q_ref, g) * scale, _tile(do_ref, g),
+                           lse_ref[0, g], delta_ref[0, g], keep)
+            dq_acc[g] = dq_acc[g] + _dot(kt, dst, _NN)        # (hd, bq)
+
+    _by_causal_block(body, q_start, bq, k_start, bk)
+
+    @pl.when(ik == nk - 1)
+    def _finish():
+        for g in range(G):
+            dq_ref[0, g] = (dq_acc[g] * scale).astype(dq_ref.dtype)
+
+
+def _bwd(q, k, v, o, lse, do, *, scale, block_q, block_k, interpret):
+    B, S, H, hd = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    bq, bk = _block_sizes(S, block_q, block_k)
+    assert fits(S, hd) and S % bq == 0 and S % bk == 0, (S, hd, bq, bk)
+    nq, nk = S // bq, S // bk
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = delta.transpose(0, 2, 1)[:, :, None, :]           # (B,H,1,S)
+    qt, kt, vt, dot_ = (_feature_major(x) for x in (q, k, v, do))
+    kw = dict(scale=scale, G=G, bq=bq, bk=bk)
+
+    # dk, dv: k block fixed on axis 2, q blocks innermost
+    qb = _q_block(bq, bk)
+    q_in = pl.BlockSpec((1, G, hd, bq),
+                        lambda b, j, i, t: (b, j, 0, qb(i, t)))
+    row_in = pl.BlockSpec((1, G, 1, bq),
+                          lambda b, j, i, t: (b, j, 0, qb(i, t)))
+    kv_fixed = pl.BlockSpec((1, 1, hd, bk), lambda b, j, i, t: (b, j, 0, i))
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, nq=nq, **kw),
+        name="flash_attention_dkv",
+        grid=(B, KV, nk, nq),
+        in_specs=[q_in, kv_fixed, kv_fixed, q_in, row_in, row_in],
+        out_specs=[kv_fixed, kv_fixed],
+        out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((hd, bk), jnp.float32),
+                        pltpu.VMEM((hd, bk), jnp.float32)],
+        compiler_params=_params(),
+        interpret=interpret,
+    )(qt, kt, vt, dot_, lse, delta)
+
+    # dq: q block fixed on axis 2, k blocks innermost
+    kb = _k_block(bq, bk)
+    q_fixed = pl.BlockSpec((1, G, hd, bq), lambda b, j, i, t: (b, j, 0, i))
+    row_fixed = pl.BlockSpec((1, G, 1, bq), lambda b, j, i, t: (b, j, 0, i))
+    kv_in = pl.BlockSpec((1, 1, hd, bk),
+                         lambda b, j, i, t: (b, j, 0, kb(i, t)))
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, nk=nk, **kw),
+        name="flash_attention_dq",
+        grid=(B, KV, nq, nk),
+        in_specs=[q_fixed, kv_in, kv_in, q_fixed, row_fixed, row_fixed],
+        out_specs=q_fixed,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((G, hd, bq), jnp.float32)],
+        compiler_params=_params(),
+        interpret=interpret,
+    )(qt, kt, vt, dot_, lse, delta)
+    return (_from_feature_major(dq), _from_feature_major(dk),
+            _from_feature_major(dv))
+
+
+def flash_attention_bwd(q, k, v, o, lse, do, *, scale: float = None,
+                        block_q: Optional[int] = None,
+                        block_k: Optional[int] = None,
+                        interpret: Optional[bool] = None):
+    """Gradients (dq, dk, dv) of ``flash_attention_fwd``'s o, given its
+    residuals o and lse and the cotangent do."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    return on_platform(
+        functools.partial(_bwd, scale=scale, block_q=block_q,
+                          block_k=block_k),
+        q, k, v, o, lse, do, interpret=interpret)

@@ -1,14 +1,15 @@
-"""Jitted wrappers around the Pallas kernels, with a global enable switch.
+"""Jitted wrappers around the Pallas kernels.
 
 Each kernel compiles natively where it is lowered for a TPU and runs in
-interpret mode elsewhere (``kernels/platform.py``).  Model code consults
-``pallas_enabled()``, which is off unless ``REPRO_USE_PALLAS=1`` or
-``set_pallas(True)``: off, train and prefill take the XLA path
-(``kernels/blocked.py``, the jnp SSD scan).
+interpret mode elsewhere (``kernels/platform.py``).
 
-The flash-attention wrapper attaches a custom VJP whose backward pass
-recomputes attention via the memory-efficient reference path (flash-style
-recompute — nothing quadratic is saved between fwd and bwd).
+Attention needs no switch: ``causal_attention`` takes the Pallas flash op
+(forward and backward kernels, ``kernels/flash_attention.py``) where the call
+is lowered for a TPU and ``kernels/blocked.py``'s XLA scan elsewhere;
+``models/attention.py`` sends it only the shapes the kernels take.  The
+model takes the SSD kernel only where ``pallas_enabled()``, off unless
+``REPRO_USE_PALLAS=1`` or ``set_pallas(True)``: off, it runs the jnp scan.
+No model path calls the RMSNorm kernel.
 """
 from __future__ import annotations
 
@@ -17,10 +18,10 @@ import os
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 
-from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention_fwd
+from repro.kernels.blocked import blocked_attention
+from repro.kernels.flash_attention import (flash_attention_bwd,
+                                           flash_attention_fwd)
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_chunked_pallas
 
@@ -36,36 +37,47 @@ def set_pallas(enabled: bool):
 
 
 # ---------------------------------------------------------------------------
-# flash attention (fwd kernel + recompute bwd)
+# flash attention (Pallas forward and backward kernels)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal, scale, interpret):
-    return flash_attention_fwd(q, k, v, causal=causal, scale=scale,
-                               interpret=interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, scale, block_q, block_k, interpret):
+    return flash_attention_fwd(q, k, v, scale=scale, block_q=block_q,
+                               block_k=block_k, interpret=interpret)[0]
 
 
-def _flash_fwd(q, k, v, causal, scale, interpret):
-    out = flash_attention_fwd(q, k, v, causal=causal, scale=scale,
-                              interpret=interpret)
-    return out, (q, k, v)
+def _flash_fwd(q, k, v, scale, block_q, block_k, interpret):
+    o, lse = flash_attention_fwd(q, k, v, scale=scale, block_q=block_q,
+                                 block_k=block_k, interpret=interpret)
+    return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, scale, interpret, res, g):
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: ref.flash_attention_ref(
-            q_, k_, v_, causal=causal, scale=scale), q, k, v)
-    return vjp(g)
+def _flash_bwd(scale, block_q, block_k, interpret, res, do):
+    return flash_attention_bwd(*res, do, scale=scale, block_q=block_q,
+                               block_k=block_k, interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
+def flash_attention(q, k, v, *, scale: float = None,
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: Optional[bool] = None):
+    """Causal attention, q: (B,S,H,hd); k,v: (B,S,KV,hd) -> (B,S,H,hd),
+    differentiable."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
-    return _flash(q, k, v, causal, scale, interpret)
+    return _flash(q, k, v, scale, block_q, block_k, interpret)
+
+
+def causal_attention(q, k, v, *, scale: float):
+    """Causal self-attention: the Pallas flash op where the call is lowered
+    for a TPU, the blocked XLA scan elsewhere.  The caller checks that the
+    kernels take the shapes (``flash_attention.fits``)."""
+    return jax.lax.platform_dependent(
+        q, k, v,
+        tpu=functools.partial(flash_attention, scale=scale, interpret=False),
+        default=lambda q, k, v: blocked_attention(q, k, v, True, scale))
 
 
 # ---------------------------------------------------------------------------

@@ -16,12 +16,22 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import apply_rope, rmsnorm
-from repro.sharding import can_shard, shard_constraint
+from repro.sharding import can_shard, current_rules, shard_constraint
 
 
-def _use_flash(cfg: ModelConfig, mode: str) -> bool:
-    from repro.kernels import ops as kops
-    return kops.pallas_enabled() and mode in ("train", "prefill")
+def _use_flash(mode: str, q, k, v, *, causal: bool, kv_override) -> bool:
+    """Whether causal self-attention may take the Pallas flash op (on a TPU;
+    ``kernels.ops.causal_attention`` picks by platform): train or prefill,
+    q and k of one length from position 0, equal q/k and v head sizes the
+    kernels' blocks tile, and one device (under GSPMD a Mosaic call would be
+    replicated)."""
+    from repro.kernels import flash_attention as fa
+    S, hd = q.shape[1], q.shape[-1]
+    rules = current_rules()
+    one_device = rules is None or rules.mesh is None or rules.mesh.size == 1
+    return (causal and kv_override is None and mode in ("train", "prefill")
+            and k.shape[1] == S and v.shape[-1] == hd and fa.fits(S, hd)
+            and one_device)
 
 
 # ---------------------------------------------------------------------------
@@ -116,9 +126,9 @@ def attn_forward(cfg: ModelConfig, p: dict, x, *, positions, mode: str,
             kv_len = None
 
     scale = hd ** -0.5
-    if kv_override is None and _use_flash(cfg, mode) and causal:
+    if _use_flash(mode, q, k, v, causal=causal, kv_override=kv_override):
         from repro.kernels import ops as kops
-        out = kops.flash_attention(q, k, v, causal=True, scale=scale)
+        out = kops.causal_attention(q, k, v, scale=scale)
     elif mode == "decode":
         out = _grouped_attention(q, k, v, causal=causal, q_pos0=pos,
                                  scale=scale, kv_len=kv_len)

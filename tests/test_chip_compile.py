@@ -46,8 +46,38 @@ def test_flash_attention_compiles_at_yi6b_heads(one_chip, dtype):
     # yi-6b: 32 query heads, 4 KV heads, head_dim 128; seq 2048
     q = ((1, 2048, 32, 128), dtype)
     kv = ((1, 2048, 4, 128), dtype)
-    _compile(lambda q, k, v: ops.flash_attention(q, k, v, causal=True),
+    _compile(lambda q, k, v: ops.flash_attention(q, k, v),
              one_chip, q, kv, kv)
+
+
+def test_flash_attention_grad_compiles_at_yi6b_train_shape(one_chip):
+    # the benchmark's train shape: batch 4 x 2048, 32 query / 4 KV heads
+    q = ((4, 2048, 32, 128), jnp.float32)
+    kv = ((4, 2048, 4, 128), jnp.float32)
+    grad = jax.grad(lambda q, k, v: jnp.sum(ops.flash_attention(q, k, v)),
+                    (0, 1, 2))
+    _compile(grad, one_chip, q, kv, kv)
+
+
+def test_yi6b_train_step_holds_the_flash_kernels(one_chip):
+    from repro.configs import get_config
+    from repro.models import loss_fn
+    from repro.models.params import abstract_params
+    cfg = get_config("yi-6b").with_(num_layers=2, vocab_size=8000,
+                                    dtype="float32", expected_params=0.0)
+
+    def grads(p, batch):
+        return jax.grad(lambda p: loss_fn(cfg, p, batch)[0])(p)
+
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        abstract_params(cfg))
+    tokens = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=one_chip)
+    text = jax.jit(grads).lower(
+        params, {"tokens": tokens, "labels": tokens}).as_text()
+    for name in ("flash_attention_fwd", "flash_attention_dkv",
+                 "flash_attention_dq"):
+        assert name in text, name
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])

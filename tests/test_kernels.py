@@ -23,37 +23,39 @@ def _qkv(B, S, H, KV, hd, dtype):
     return q, k, v
 
 
+# the kernels take lane-aligned heads and lengths (head_dim and S
+# multiples of 128); other shapes keep the blocked path
 @pytest.mark.parametrize("B,S,H,KV,hd", [
-    (1, 128, 4, 4, 64),     # MHA
-    (2, 256, 4, 2, 64),     # GQA
-    (1, 512, 8, 2, 32),     # long-ish, high group ratio
-    (2, 128, 6, 3, 128),    # non-pow2 heads, MXU-width head_dim
+    (1, 128, 4, 4, 128),    # MHA
+    (2, 256, 4, 2, 128),    # GQA
+    (1, 512, 8, 2, 128),    # long-ish, high group ratio
+    (2, 128, 6, 3, 128),    # non-pow2 heads
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_vs_ref(B, S, H, KV, hd, dtype):
     q, k, v = _qkv(B, S, H, KV, hd, dtype)
-    out = ops.flash_attention(q, k, v, causal=True, interpret=True)
+    out = ops.flash_attention(q, k, v, interpret=True)
     exp = ref.flash_attention_ref(q, k, v, causal=True)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("block_q,block_k", [(64, 64), (128, 32), (32, 128)])
+@pytest.mark.parametrize("block_q,block_k", [(128, 128), (256, 128),
+                                             (128, 256)])
 def test_flash_attention_block_shapes(block_q, block_k):
-    q, k, v = _qkv(2, 256, 4, 2, 64, jnp.float32)
-    from repro.kernels.flash_attention import flash_attention_fwd
-    out = flash_attention_fwd(q, k, v, causal=True, block_q=block_q,
-                              block_k=block_k, interpret=True)
+    q, k, v = _qkv(2, 512, 4, 2, 128, jnp.float32)
+    out = ops.flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                              interpret=True)
     exp = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5,
                                rtol=2e-5)
 
 
 def test_flash_attention_grad_flows():
-    q, k, v = _qkv(1, 128, 4, 2, 32, jnp.float32)
+    q, k, v = _qkv(1, 128, 4, 2, 128, jnp.float32)
     g = jax.grad(lambda q_: jnp.sum(
-        ops.flash_attention(q_, k, v, causal=True, interpret=True) ** 2))(q)
+        ops.flash_attention(q_, k, v, interpret=True) ** 2))(q)
     gr = jax.grad(lambda q_: jnp.sum(
         ref.flash_attention_ref(q_, k, v, causal=True) ** 2))(q)
     np.testing.assert_allclose(np.asarray(g), np.asarray(gr), atol=1e-4,

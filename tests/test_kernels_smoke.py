@@ -9,19 +9,52 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.flash_attention import flash_attention_fwd
 
 KEY = jax.random.PRNGKey(0)
 
 
-def test_flash_attention_smoke():
+def _gqa_qkv():
+    # GQA at the kernels' smallest lane-aligned shape: 2 blocks of 128 rows
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (1, 128, 2, 32), jnp.float32)
-    k = jax.random.normal(ks[1], (1, 128, 2, 32), jnp.float32)
-    v = jax.random.normal(ks[2], (1, 128, 2, 32), jnp.float32)
-    out = ops.flash_attention(q, k, v, causal=True, interpret=True)
+    q = jax.random.normal(ks[0], (1, 256, 4, 128), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 256, 2, 128), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 256, 2, 128), jnp.float32)
+    return q, k, v
+
+
+def test_flash_attention_smoke():
+    q, k, v = _gqa_qkv()
+    out = ops.flash_attention(q, k, v, block_q=128, block_k=128,
+                              interpret=True)
     exp = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5,
                                rtol=2e-5)
+
+
+def test_flash_attention_lse_smoke():
+    q, k, v = _gqa_qkv()
+    _, lse = flash_attention_fwd(q, k, v, block_q=128, block_k=128,
+                                 interpret=True)
+    B, S, H, hd = q.shape
+    s = jnp.einsum("bskgh,btkh->bkgst", q.reshape(B, S, 2, 2, hd), k)
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s * hd ** -0.5, -jnp.inf)
+    exp = jax.nn.logsumexp(s, axis=-1).reshape(B, H, 1, S)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(exp), atol=2e-5,
+                               rtol=2e-5)
+
+
+def test_flash_attention_grad_smoke():
+    q, k, v = _gqa_qkv()
+    w = jax.random.normal(jax.random.PRNGKey(1), q.shape, jnp.float32)
+    got = jax.grad(lambda *a: jnp.sum(ops.flash_attention(
+        *a, block_q=128, block_k=128, interpret=True) * w),
+        (0, 1, 2))(q, k, v)
+    exp = jax.grad(lambda *a: jnp.sum(
+        ref.flash_attention_ref(*a, causal=True) * w), (0, 1, 2))(q, k, v)
+    for g, e in zip(got, exp):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), atol=2e-5,
+                                   rtol=2e-5)
 
 
 def test_ssd_smoke():
