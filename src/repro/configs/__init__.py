@@ -16,9 +16,9 @@ from repro.configs.base import (ATTN, FF_GELU, FF_MOE, FF_NONE, FF_RELU2,
 
 # populate the registry
 from repro.configs import (chameleon_34b, deepseek_v2_236b,  # noqa: F401
-                           granite_moe_3b_a800m, jamba_v0_1_52b, mamba2_1_3b,
-                           minitron_4b, seamless_m4t_large_v2, starcoder2_7b,
-                           yi_6b, yi_9b)
+                           granite_4_0_h_micro, granite_moe_3b_a800m,
+                           jamba_v0_1_52b, mamba2_1_3b, minitron_4b,
+                           seamless_m4t_large_v2, starcoder2_7b, yi_6b, yi_9b)
 
 ALL_ARCHS = list_archs()
 

@@ -102,6 +102,16 @@ class ModelConfig:
     # masked in the loss and sliced off in serving.
     vocab_pad_to: int = 256
     rope_theta: float = 10_000.0
+    # "rope" rotates q and k by position; "nope" leaves them as projected
+    position_embedding_type: str = "rope"
+    # softmax scale of attention scores; None => head_dim ** -0.5
+    attention_multiplier: Optional[float] = None
+    # muP-style scalars (Granite 4.0): the embedding lookup is multiplied by
+    # embedding_multiplier, every residual branch by residual_multiplier, and
+    # the logits are divided by logits_scaling.  1.0 leaves each out.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     norm_eps: float = 1e-5
     qk_norm: bool = False           # chameleon-style per-head q/k RMSNorm
     tie_embeddings: bool = False

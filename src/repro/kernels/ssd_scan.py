@@ -55,7 +55,8 @@ def _ssd_kernel(a_ref, x_ref, dtc_ref, dtr_ref, b_ref, c_ref, y_ref, h_ref):
                       keepdims=True)
 
     # --- intra-chunk quadratic term ---
-    decay = jnp.where(causal, jnp.exp(cum_col - cum_row), 0.0)     # (Q,Q)
+    # mask before exp: above the diagonal the exponent is positive
+    decay = jnp.exp(jnp.where(causal, cum_col - cum_row, -jnp.inf))  # (Q,Q)
     cb = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (Q,Q)
     scores = cb * decay * dt_row                                  # dt_s on cols

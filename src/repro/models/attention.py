@@ -91,6 +91,7 @@ def attn_forward(cfg: ModelConfig, p: dict, x, *, positions, mode: str,
     # and let GSPMD propagate (blocked attention regroups H -> (KV, G), so a
     # head-sharding that KV cannot carry would replicate the score tiles).
     head_par = can_shard(KV, "kv_heads") and mode != "decode"
+    rope = cfg.position_embedding_type == "rope"
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     if head_par:
         q = shard_constraint(q, "batch", None, "heads", None)
@@ -98,7 +99,8 @@ def attn_forward(cfg: ModelConfig, p: dict, x, *, positions, mode: str,
     if kv_override is not None:
         k, v = kv_override
         new_cache = cache
-        q = apply_rope(q, positions, cfg.rope_theta) if causal else q
+        if causal and rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
         kv_len = None
     else:
         k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
@@ -109,8 +111,9 @@ def attn_forward(cfg: ModelConfig, p: dict, x, *, positions, mode: str,
         if cfg.qk_norm:
             q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
             k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        if rope:
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
         if mode == "decode":
             assert cache is not None and pos is not None
             ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, pos, axis=1)
@@ -125,7 +128,8 @@ def attn_forward(cfg: ModelConfig, p: dict, x, *, positions, mode: str,
                 new_cache = None
             kv_len = None
 
-    scale = hd ** -0.5
+    scale = (hd ** -0.5 if cfg.attention_multiplier is None
+             else cfg.attention_multiplier)
     if _use_flash(mode, q, k, v, causal=causal, kv_override=kv_override):
         from repro.kernels import ops as kops
         out = kops.causal_attention(q, k, v, scale=scale)

@@ -41,6 +41,8 @@ LOSS_CHUNK = 512
 @jax.named_scope("embed")
 def _embed(cfg: ModelConfig, params, tokens):
     x = params["embed"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     return shard_constraint(x, "batch", "seq", "embed")
 
 
@@ -48,6 +50,14 @@ def _head_weight(cfg: ModelConfig, params):
     if cfg.tie_embeddings:
         return params["embed"].T          # (D, V)
     return params["lm_head"]
+
+
+def _head_input(cfg: ModelConfig, x):
+    """The final hidden state as the head takes it: the head is linear, so
+    dividing its input by ``logits_scaling`` divides the logits."""
+    if cfg.logits_scaling != 1.0:
+        x = x / cfg.logits_scaling
+    return x
 
 
 # ---------------------------------------------------------------------------
@@ -78,7 +88,7 @@ def loss_fn(cfg: ModelConfig, params, batch) -> Tuple[jax.Array, dict]:
     w_head = _head_weight(cfg, params)
     with jax.named_scope("head"):
         loss_sum, weight = chunked_softmax_xent(
-            hidden, w_head, batch["labels"],
+            _head_input(cfg, hidden), w_head, batch["labels"],
             chunk=min(LOSS_CHUNK, hidden.shape[1]),
             valid_vocab=cfg.vocab_size)
     xent = loss_sum / jnp.maximum(weight, 1.0)
@@ -204,7 +214,7 @@ def prefill(cfg: ModelConfig, params, batch):
     x, cache, _ = tfm.decoder(cfg, params["decoder"], x, positions=positions,
                               mode="prefill", cache=None, pos=None,
                               enc_out=enc_out)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = _head_input(cfg, rmsnorm(x, params["final_norm"], cfg.norm_eps))
     logits = jnp.einsum("bd,dv->bv", x[:, -1, :], _head_weight(cfg, params))
     logits = shard_constraint(logits, "batch", "vocab")
     return cache, logits[:, :cfg.vocab_size].astype(jnp.float32)
@@ -217,7 +227,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos):
     x, new_cache, _ = tfm.decoder(cfg, params["decoder"], x,
                                   positions=positions, mode="decode",
                                   cache=cache, pos=pos, enc_out=None)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = _head_input(cfg, rmsnorm(x, params["final_norm"], cfg.norm_eps))
     logits = jnp.einsum("bsd,dv->bsv", x, _head_weight(cfg, params))
     logits = shard_constraint(logits, "batch", None, "vocab")
     return logits[:, 0, :cfg.vocab_size].astype(jnp.float32), new_cache

@@ -98,9 +98,11 @@ def ssd_chunked(x, dt, a_log, b, c, *, chunk: int):
         ch = shard_constraint(jnp.repeat(cq, rep, axis=2),
                               "batch", None, "ssm_heads", None)
         cum = jnp.cumsum(dtq * A, axis=1)                    # (B,Q,H)
-        # intra-chunk quadratic term
+        # intra-chunk quadratic term; above the diagonal diff is positive
+        # and exp would overflow, so mask before exp: exp(-inf) = 0 there,
+        # with a zero gradient
         diff = cum[:, :, None, :] - cum[:, None, :, :]       # (B,Q,Q,H)
-        decay = jnp.where(tri[None, :, :, None], jnp.exp(diff), 0.0)
+        decay = jnp.exp(jnp.where(tri[None, :, :, None], diff, -jnp.inf))
         decay = shard_constraint(decay, "batch", None, None, "ssm_heads")
         cb = jnp.einsum("bqhs,bkhs->bqkh", ch, bh)
         scores = cb * decay * dtq[:, None, :, :]             # (B,Q,K,H)
@@ -155,7 +157,8 @@ def ssm_forward(cfg: ModelConfig, p: dict, xin, *, mode: str,
         xbc_c = jnp.einsum("bwc,wc->bc", window, w)[:, None, :] + p["conv_b"]
     else:
         new_conv = None
-        xbc_c = _causal_conv(xbc, p["conv_w"], p["conv_b"])
+        with jax.named_scope("conv"):
+            xbc_c = _causal_conv(xbc, p["conv_w"], p["conv_b"])
         if mode == "prefill":
             pad = jnp.pad(xbc, ((0, 0), (ss.conv_width - 1, 0), (0, 0)))
             new_conv = pad[:, L:L + ss.conv_width - 1, :]  # last W-1 inputs
@@ -180,10 +183,11 @@ def ssm_forward(cfg: ModelConfig, p: dict, xin, *, mode: str,
         new_cache = {"conv": new_conv, "h": h}
     else:
         from repro.kernels import ops as kops
-        if kops.pallas_enabled():
-            y = kops.ssd(xs, dt, p["a_log"], b, c, chunk=ss.chunk)
-        else:
-            y = ssd_chunked(xs, dt, p["a_log"], b, c, chunk=ss.chunk)
+        with jax.named_scope("ssd"):
+            if kops.pallas_enabled():
+                y = kops.ssd(xs, dt, p["a_log"], b, c, chunk=ss.chunk)
+            else:
+                y = ssd_chunked(xs, dt, p["a_log"], b, c, chunk=ss.chunk)
         if mode == "prefill":
             h = ssd_final_state(xs, dt, p["a_log"], b, chunk=ss.chunk)
             new_cache = {"conv": new_conv, "h": h}

@@ -50,6 +50,13 @@ def _maybe_remat(fn, mode: str):
 # Single layer
 # ---------------------------------------------------------------------------
 
+def _branch(cfg: ModelConfig, y):
+    """A residual branch's output as it is added to the stream."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return y
+
+
 def apply_layer(cfg: ModelConfig, p: dict, x, layer_idx: int, *, positions,
                 mode: str, cache: Optional[dict], pos, enc_out):
     """Returns (x, new_cache, aux_loss)."""
@@ -78,7 +85,7 @@ def apply_layer(cfg: ModelConfig, p: dict, x, layer_idx: int, *, positions,
             new_cache["ssm"] = sc
         else:
             raise ValueError(mixer)
-    x = x + y
+    x = x + _branch(cfg, y)
     x = shard_constraint(x, "batch", "seq", "embed")
 
     if "cross" in p:
@@ -97,7 +104,7 @@ def apply_layer(cfg: ModelConfig, p: dict, x, layer_idx: int, *, positions,
         y, _ = attn_mod.attn_forward(
             cfg, p["cross"], h, positions=positions, mode=mode,
             kv_override=kv, causal=False)
-        x = x + y
+        x = x + _branch(cfg, y)
         x = shard_constraint(x, "batch", "seq", "embed")
 
     if ff != FF_NONE:
@@ -110,7 +117,7 @@ def apply_layer(cfg: ModelConfig, p: dict, x, layer_idx: int, *, positions,
             from repro.models.layers import apply_ffn
             with jax.named_scope("ffn"):
                 y = apply_ffn(p["ff"], h, ff)
-        x = x + y
+        x = x + _branch(cfg, y)
         x = shard_constraint(x, "batch", "seq", "embed")
 
     new_cache = {k: v for k, v in new_cache.items() if v is not None}
@@ -128,14 +135,19 @@ def decoder(cfg: ModelConfig, dparams: dict, x, *, positions, mode: str,
     aux_total = jnp.zeros((), jnp.float32)
     new_cache = {}
 
+    def prefix_fn(i):
+        def fn(x, lp, c):
+            return apply_layer(cfg, lp, x, i, positions=positions, mode=mode,
+                               cache=c, pos=pos, enc_out=enc_out)
+        # a hybrid's layers are rematted one by one, here as in the scan
+        return _maybe_remat(fn, mode) if period > 1 else fn
+
     if prefix_n:
         new_cache["prefix"] = {}
         for i in range(prefix_n):
             name = f"layer{i}"
             c = cache["prefix"][name] if cache else None
-            x, nc, aux = apply_layer(cfg, dparams["prefix"][name], x, i,
-                                     positions=positions, mode=mode,
-                                     cache=c, pos=pos, enc_out=enc_out)
+            x, nc, aux = prefix_fn(i)(x, dparams["prefix"][name], c)
             aux_total = aux_total + aux
             if nc is not None:
                 new_cache["prefix"][name] = nc

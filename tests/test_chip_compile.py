@@ -100,3 +100,34 @@ def test_ssd_compiles_at_mamba2_widths(one_chip):
              one_chip, ((B, L, H, P), jnp.float32), ((B, L, H), jnp.float32),
              ((H,), jnp.float32), ((B, L, G, N), jnp.float32),
              ((B, L, G, N), jnp.float32))
+
+
+def test_granite4h_train_step_fits_one_chip(one_chip):
+    # the granite4h.train4k cell's step: its configuration file, f32
+    # weights with AdamW state donated, batch 2 x 4096
+    import json
+
+    from bench.harness import program
+    from repro.models import loss_fn
+    from repro.models.params import abstract_params
+    from repro.optim import AdamWConfig, abstract_opt_state, adamw_update
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs",
+                           "granite4h_micro.json")) as f:
+        cfg = program.model_config(json.load(f))
+
+    def step(p, o, batch):
+        grads = jax.grad(lambda p: loss_fn(cfg, p, batch)[0])(p)
+        p, o, _ = adamw_update(AdamWConfig(), grads, o, p, 3e-4)
+        return p, o
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+    params = on_chip(abstract_params(cfg))
+    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip)
+    mem = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, on_chip(abstract_opt_state(abstract_params(cfg))),
+        {"tokens": tokens, "labels": tokens}).compile().memory_analysis()
+    assert cfg.dtype == "float32" and cfg.num_layers == 8
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= 14e9

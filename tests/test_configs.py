@@ -9,11 +9,11 @@ from repro.configs.base import ATTN, FF_MOE, MLA, SSM
 EXPECTED_ARCHS = {
     "mamba2-1.3b", "granite-moe-3b-a800m", "deepseek-v2-236b",
     "seamless-m4t-large-v2", "starcoder2-7b", "yi-9b", "minitron-4b",
-    "yi-6b", "jamba-v0.1-52b", "chameleon-34b",
+    "yi-6b", "jamba-v0.1-52b", "chameleon-34b", "granite-4.0-h-micro",
 }
 
 
-def test_all_ten_archs_registered():
+def test_all_eleven_archs_registered():
     assert set(ALL_ARCHS) == EXPECTED_ARCHS
 
 
@@ -54,6 +54,17 @@ def test_exact_published_dims():
     assert c.enc_layers == 24 and c.vocab_size == 256_206
     c = get_config("chameleon-34b")
     assert c.qk_norm and c.d_model == 8192
+    c = get_config("granite-4.0-h-micro")
+    assert (c.num_layers, c.d_model, c.num_heads, c.num_kv_heads,
+            c.resolved_head_dim, c.d_ff, c.vocab_size) == \
+        (40, 2048, 32, 8, 64, 8192, 100_352)
+    assert [i for i in range(40) if c.mixer_at(i) == "attn"] == [5, 15, 25, 35]
+    assert (c.ssm.d_state, c.ssm.head_dim, c.ssm.expand, c.ssm.num_groups,
+            c.ssm.conv_width, c.ssm.chunk) == (128, 64, 2, 1, 4, 256)
+    assert (c.position_embedding_type, c.attention_multiplier,
+            c.embedding_multiplier, c.residual_multiplier,
+            c.logits_scaling) == ("nope", 0.015625, 12.0, 0.22, 8.0)
+    assert c.tie_embeddings and c.moe is None
 
 
 def test_moe_active_params():
@@ -82,7 +93,8 @@ def test_deepseek_first_dense_layer():
 def test_long_context_applicability():
     long = SHAPES["long_500k"]
     runnable = [a for a in ALL_ARCHS if shape_applicable(get_config(a), long)[0]]
-    assert sorted(runnable) == ["jamba-v0.1-52b", "mamba2-1.3b"]
+    assert sorted(runnable) == ["granite-4.0-h-micro", "jamba-v0.1-52b",
+                                "mamba2-1.3b"]
     # all other shapes apply to every arch
     for s in ("train_4k", "prefill_32k", "decode_32k"):
         for a in ALL_ARCHS:
@@ -92,10 +104,11 @@ def test_long_context_applicability():
 def test_cell_grid_is_40():
     from repro.launch.cells import all_cells
     cells = all_cells()
-    assert len(cells) == 40
-    # long_500k is skipped for the 8 pure full-attention archs -> 32 runnable
+    # four shapes for each of the 11 archs (40 for the first ten)
+    assert len(cells) == 44
+    # long_500k is skipped for the 8 pure full-attention archs -> 36 runnable
     runnable = [c for c in cells if c[2]]
-    assert len(runnable) == 32
+    assert len(runnable) == 36
 
 
 @pytest.mark.parametrize("arch", sorted(EXPECTED_ARCHS))

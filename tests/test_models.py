@@ -13,7 +13,7 @@ pytestmark = pytest.mark.slow
 from repro.configs import ALL_ARCHS, smoke_config
 from repro.models import (decode_step, forward_hidden, init_params, loss_fn,
                           pad_cache, prefill)
-from repro.models.model import _head_weight
+from repro.models.model import _head_input, _head_weight
 from repro.models.moe import moe_forward, set_moe_impl
 
 KEY = jax.random.PRNGKey(0)
@@ -48,7 +48,8 @@ def test_smoke_forward_and_train_step(arch):
 
 @pytest.mark.parametrize("arch", ["yi-6b", "mamba2-1.3b", "jamba-v0.1-52b",
                                   "deepseek-v2-236b", "chameleon-34b",
-                                  "seamless-m4t-large-v2"])
+                                  "seamless-m4t-large-v2",
+                                  "granite-4.0-h-micro"])
 def test_decode_matches_teacher_forcing(arch):
     """prefill + step-by-step decode reproduces the full-forward logits
     (with the exact dense-MoE path — capacity dispatch is batch-dependent)."""
@@ -63,7 +64,7 @@ def test_decode_matches_teacher_forcing(arch):
         if cfg.enc_layers:
             fb["enc_embeds"] = jax.random.normal(KEY, (B, 8, cfg.d_model))
         hid, _ = forward_hidden(cfg, params, fb, mode="train")
-        full = jnp.einsum("bsd,dv->bsv", hid,
+        full = jnp.einsum("bsd,dv->bsv", _head_input(cfg, hid),
                           _head_weight(cfg, params))[..., :cfg.vocab_size]
         pb = {"tokens": toks[:, :S0]}
         if cfg.enc_layers:
