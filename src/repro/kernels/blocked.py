@@ -4,8 +4,9 @@
 Used wherever the Pallas kernels do not run: off a TPU (the CPU tests, the
 multi-pod dry-run, which lowers on the CPU backend), on meshes of more than
 one device, and for the train and prefill calls the kernels do not take
-(non-causal or cross-attention, MLA's unequal q/v head sizes, heads or
-lengths not lane-aligned; ``models/attention.py`` ``_use_flash``).  A
+(non-causal or cross-attention, MLA's unequal q/v head sizes, head sizes
+not a multiple of 64 or lengths not of 128; ``models/attention.py``
+``_use_flash``).  A
 ``lax.scan`` over KV blocks with online softmax keeps the live working set
 at one (B,KV,G,Sq,block_k) tile instead of the full O(Sq x Sk) score matrix
 (2.1 GB/device/tensor on yi-6b train_4k — see EXPERIMENTS.md §Perf

@@ -6,6 +6,10 @@ layout XLA gives the attention projections' outputs on a TPU (sequence
 minor), so the transposes around the kernels are bitcasts, not copies.  A
 head's block is a (hd, rows) tile: queries and keys lie on lanes, and every
 softmax statistic is a (1, block_q) row, so no kernel reduces across lanes.
+hd lies on sublanes, so any multiple of 64 tiles whole (8, 128) f32 and
+(16, 128) bf16 tiles (``fits``); below 128 the products that contract over
+hd fill half the MXU's depth and each grid step does less work, so the
+blocks are chosen by head size (``blocks``).
 
 One grid step serves the G = H/KV query heads of one KV head: the q block
 is heads [j*G, (j+1)*G) and the k/v block KV head j.  GQA is thus in the
@@ -42,13 +46,24 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.platform import on_platform
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
-# (block_q, block_k) of all three kernels, from a sweep on one v5e chip at
-# (4, 2048, 32/4, 128) in f32 (recorded in PERF.md)
-BLOCKS = (512, 512)
+# preferred (block_q, block_k) of each kernel by head size, from sweeps on
+# one v5e chip in f32 (recorded in PERF.md): hd 128 at (4, 2048, 32/4, 128),
+# hd 64 at (2, 4096, 32/8, 64)
+_BLOCKS = {
+    128: {"fwd": (512, 512), "dkv": (512, 512), "dq": (512, 512)},
+    64: {"fwd": (512, 1024), "dkv": (512, 512), "dq": (512, 512)},
+}
+_HEAD = 64              # heads are a whole number of these rows
 _LANES = 128
 _VMEM_LIMIT = 64 * 2**20
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def blocks(hd: int, kernel: str) -> tuple:
+    """Preferred (block_q, block_k) of ``kernel`` ("fwd", "dkv" or "dq")
+    at head size ``hd``: hd 64's sweep below 128, hd 128's from there."""
+    return _BLOCKS[64 if hd < 128 else 128][kernel]
 
 
 def _block(S: int, preferred: int, given: Optional[int]) -> int:
@@ -62,14 +77,21 @@ def _block(S: int, preferred: int, given: Optional[int]) -> int:
     return b
 
 
-def _block_sizes(S: int, block_q: Optional[int], block_k: Optional[int]):
-    return _block(S, BLOCKS[0], block_q), _block(S, BLOCKS[1], block_k)
+def _checked_blocks(kernel: str, S: int, hd: int, block_q: Optional[int],
+                    block_k: Optional[int]) -> tuple:
+    """``kernel``'s (block_q, block_k) at (S, hd): the given sizes, else
+    the preferred ones that divide S."""
+    pq, pk = blocks(hd, kernel)
+    bq, bk = _block(S, pq, block_q), _block(S, pk, block_k)
+    assert fits(S, hd) and S % bq == 0 and S % bk == 0, (S, hd, bq, bk)
+    return bq, bk
 
 
 def fits(S: int, hd: int) -> bool:
     """Whether the kernels take sequence length ``S`` and head size ``hd``:
-    lane-aligned heads and rows (every block size then divides S)."""
-    return hd % _LANES == 0 and S % _LANES == 0
+    heads of whole 64-row tiles (hd on sublanes) and lane-aligned rows
+    (every block size then divides S)."""
+    return hd % _HEAD == 0 and S % _LANES == 0
 
 
 def _dot(a, b, dims):
@@ -169,8 +191,7 @@ def _fwd(q, k, v, *, scale, block_q, block_k, interpret):
     B, S, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
-    bq, bk = _block_sizes(S, block_q, block_k)
-    assert fits(S, hd) and S % bq == 0 and S % bk == 0, (S, hd, bq, bk)
+    bq, bk = _checked_blocks("fwd", S, hd, block_q, block_k)
     nq, nk = S // bq, S // bk
     kernel = functools.partial(_fwd_kernel, scale=scale, G=G, bq=bq, bk=bk,
                                nk=nk)
@@ -284,56 +305,68 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             dq_ref[0, g] = (dq_acc[g] * scale).astype(dq_ref.dtype)
 
 
-def _bwd(q, k, v, o, lse, do, *, scale, block_q, block_k, interpret):
-    B, S, H, hd = q.shape
-    KV = k.shape[2]
+def _dkv(qt, kt, vt, dot_, lse, delta, *, scale, bq, bk, interpret):
+    """dk, dv feature-major: k block fixed on grid axis 2, q blocks
+    innermost."""
+    B, H, hd, S = qt.shape
+    KV = kt.shape[1]
     G = H // KV
-    bq, bk = _block_sizes(S, block_q, block_k)
-    assert fits(S, hd) and S % bq == 0 and S % bk == 0, (S, hd, bq, bk)
-    nq, nk = S // bq, S // bk
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    delta = delta.transpose(0, 2, 1)[:, :, None, :]           # (B,H,1,S)
-    qt, kt, vt, dot_ = (_feature_major(x) for x in (q, k, v, do))
-    kw = dict(scale=scale, G=G, bq=bq, bk=bk)
-
-    # dk, dv: k block fixed on axis 2, q blocks innermost
     qb = _q_block(bq, bk)
     q_in = pl.BlockSpec((1, G, hd, bq),
                         lambda b, j, i, t: (b, j, 0, qb(i, t)))
     row_in = pl.BlockSpec((1, G, 1, bq),
                           lambda b, j, i, t: (b, j, 0, qb(i, t)))
     kv_fixed = pl.BlockSpec((1, 1, hd, bk), lambda b, j, i, t: (b, j, 0, i))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, nq=nq, **kw),
+    return pl.pallas_call(
+        functools.partial(_dkv_kernel, scale=scale, G=G, bq=bq, bk=bk,
+                          nq=S // bq),
         name="flash_attention_dkv",
-        grid=(B, KV, nk, nq),
+        grid=(B, KV, S // bk, S // bq),
         in_specs=[q_in, kv_fixed, kv_fixed, q_in, row_in, row_in],
         out_specs=[kv_fixed, kv_fixed],
-        out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype),
-                   jax.ShapeDtypeStruct(vt.shape, v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
         scratch_shapes=[pltpu.VMEM((hd, bk), jnp.float32),
                         pltpu.VMEM((hd, bk), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret,
     )(qt, kt, vt, dot_, lse, delta)
 
-    # dq: q block fixed on axis 2, k blocks innermost
+
+def _dq(qt, kt, vt, dot_, lse, delta, *, scale, bq, bk, interpret):
+    """dq feature-major: q block fixed on grid axis 2, k blocks
+    innermost."""
+    B, H, hd, S = qt.shape
+    KV = kt.shape[1]
+    G = H // KV
     kb = _k_block(bq, bk)
     q_fixed = pl.BlockSpec((1, G, hd, bq), lambda b, j, i, t: (b, j, 0, i))
     row_fixed = pl.BlockSpec((1, G, 1, bq), lambda b, j, i, t: (b, j, 0, i))
     kv_in = pl.BlockSpec((1, 1, hd, bk),
                          lambda b, j, i, t: (b, j, 0, kb(i, t)))
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, nk=nk, **kw),
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, G=G, bq=bq, bk=bk,
+                          nk=S // bk),
         name="flash_attention_dq",
-        grid=(B, KV, nq, nk),
+        grid=(B, KV, S // bq, S // bk),
         in_specs=[q_fixed, kv_in, kv_in, q_fixed, row_fixed, row_fixed],
         out_specs=q_fixed,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
         scratch_shapes=[pltpu.VMEM((G, hd, bq), jnp.float32)],
         compiler_params=_params(),
         interpret=interpret,
     )(qt, kt, vt, dot_, lse, delta)
+
+
+def _bwd(q, k, v, o, lse, do, *, scale, block_q, block_k, interpret):
+    S, hd = q.shape[1], q.shape[-1]
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    delta = delta.transpose(0, 2, 1)[:, :, None, :]           # (B,H,1,S)
+    args = [_feature_major(x) for x in (q, k, v, do)] + [lse, delta]
+    bq, bk = _checked_blocks("dkv", S, hd, block_q, block_k)
+    dk, dv = _dkv(*args, scale=scale, bq=bq, bk=bk, interpret=interpret)
+    bq, bk = _checked_blocks("dq", S, hd, block_q, block_k)
+    dq = _dq(*args, scale=scale, bq=bq, bk=bk, interpret=interpret)
     return (_from_feature_major(dq), _from_feature_major(dk),
             _from_feature_major(dv))
 

@@ -1,7 +1,8 @@
 """Which attention path the model takes.  Causal self-attention in train or
-prefill, at shapes the flash kernels' blocks tile and on one device, goes
-through ``kernels.ops.causal_attention``, which takes the Pallas op only
-where the call is lowered for a TPU; every other call keeps its path."""
+prefill, at shapes the flash kernels' blocks tile (head sizes a multiple of
+64, lengths of 128) and on one device, goes through
+``kernels.ops.causal_attention``, which takes the Pallas op only where the
+call is lowered for a TPU; every other call keeps its path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,9 +22,16 @@ def _qkv(S=256, Sk=None, hd=128, hdv=None):
             jnp.zeros((1, Sk, 2, hdv or hd)))
 
 
+@pytest.mark.parametrize("hd", [64, 128])
 @pytest.mark.parametrize("mode", ["train", "prefill"])
-def test_causal_self_attention_may_take_flash(mode):
-    assert A._use_flash(mode, *_qkv(), causal=True, kv_override=None)
+def test_causal_self_attention_may_take_flash(mode, hd):
+    assert A._use_flash(mode, *_qkv(hd=hd), causal=True, kv_override=None)
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dkv", "dq"])
+def test_hd128_keeps_its_blocks(kernel):
+    from repro.kernels import flash_attention as fa
+    assert fa.blocks(128, kernel) == (512, 512)
 
 
 def test_one_device_mesh_may_take_flash():
@@ -34,7 +42,7 @@ def test_one_device_mesh_may_take_flash():
 
 @pytest.mark.parametrize("case", [
     "decode", "cross_attention", "non_causal", "hd_ne_hdv", "hd_unaligned",
-    "seq_unaligned", "q_shorter_than_k", "two_devices"])
+    "hd_below_a_tile", "seq_unaligned", "q_shorter_than_k", "two_devices"])
 def test_other_calls_keep_the_blocked_path(case):
     mode, causal, kv_override, qkv = "train", True, None, _qkv()
     if case == "decode":
@@ -46,7 +54,9 @@ def test_other_calls_keep_the_blocked_path(case):
     elif case == "hd_ne_hdv":          # MLA: q/k heads 192, v heads 128
         qkv = _qkv(hd=256, hdv=128)
     elif case == "hd_unaligned":
-        qkv = _qkv(hd=64)
+        qkv = _qkv(hd=96)
+    elif case == "hd_below_a_tile":
+        qkv = _qkv(hd=32)
     elif case == "seq_unaligned":
         qkv = _qkv(S=200)
     elif case == "q_shorter_than_k":

@@ -59,6 +59,25 @@ def test_flash_attention_grad_compiles_at_yi6b_train_shape(one_chip):
     _compile(grad, one_chip, q, kv, kv)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_attention_compiles_at_granite4h_heads(one_chip, dtype):
+    # granite-4.0-h: 32 query heads, 8 KV heads, head_dim 64; the
+    # granite4h.train4k cell's batch 2 x 4096
+    q = ((2, 4096, 32, 64), dtype)
+    kv = ((2, 4096, 8, 64), dtype)
+    _compile(lambda q, k, v: ops.flash_attention(q, k, v, scale=0.015625),
+             one_chip, q, kv, kv)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_attention_grad_compiles_at_granite4h_heads(one_chip, dtype):
+    q = ((2, 4096, 32, 64), dtype)
+    kv = ((2, 4096, 8, 64), dtype)
+    grad = jax.grad(lambda q, k, v: jnp.sum(ops.flash_attention(
+        q, k, v, scale=0.015625).astype(jnp.float32)), (0, 1, 2))
+    _compile(grad, one_chip, q, kv, kv)
+
+
 def test_yi6b_train_step_holds_the_flash_kernels(one_chip):
     from repro.configs import get_config
     from repro.models import loss_fn
@@ -126,8 +145,14 @@ def test_granite4h_train_step_fits_one_chip(one_chip):
             s.shape, s.dtype, sharding=one_chip), tree)
     params = on_chip(abstract_params(cfg))
     tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=one_chip)
-    mem = jax.jit(step, donate_argnums=(0, 1)).lower(
+    lowered = jax.jit(step, donate_argnums=(0, 1)).lower(
         params, on_chip(abstract_opt_state(abstract_params(cfg))),
-        {"tokens": tokens, "labels": tokens}).compile().memory_analysis()
+        {"tokens": tokens, "labels": tokens})
+    # its hd-64 attention layer takes the flash kernels
+    text = lowered.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_dkv",
+                 "flash_attention_dq"):
+        assert name in text, name
+    mem = lowered.compile().memory_analysis()
     assert cfg.dtype == "float32" and cfg.num_layers == 8
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes <= 14e9

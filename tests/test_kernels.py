@@ -23,13 +23,15 @@ def _qkv(B, S, H, KV, hd, dtype):
     return q, k, v
 
 
-# the kernels take lane-aligned heads and lengths (head_dim and S
-# multiples of 128); other shapes keep the blocked path
+# the kernels take heads of whole 64-row tiles and lane-aligned lengths
+# (head_dim a multiple of 64, S of 128); other shapes keep the blocked path
 @pytest.mark.parametrize("B,S,H,KV,hd", [
     (1, 128, 4, 4, 128),    # MHA
     (2, 256, 4, 2, 128),    # GQA
     (1, 512, 8, 2, 128),    # long-ish, high group ratio
     (2, 128, 6, 3, 128),    # non-pow2 heads
+    (2, 256, 8, 2, 64),     # GQA at hd 64
+    (1, 512, 4, 1, 64),     # hd 64, one KV head
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_vs_ref(B, S, H, KV, hd, dtype):
@@ -50,6 +52,26 @@ def test_flash_attention_block_shapes(block_q, block_k):
     exp = ref.flash_attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5,
                                rtol=2e-5)
+
+
+def test_flash_attention_hd64_default_blocks_fwd_and_grads():
+    # hd 64's preferred blocks run as chosen: S holds two of the largest
+    from repro.kernels import flash_attention as fa
+    S = 2 * max(max(fa.blocks(64, k)) for k in ("fwd", "dkv", "dq"))
+    q, k, v = _qkv(1, S, 2, 1, 64, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), q.shape)
+    out = ops.flash_attention(q, k, v, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(ref.flash_attention_ref(q, k, v, causal=True)),
+        atol=2e-5, rtol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(ops.flash_attention(
+        *a, interpret=True) * w), (0, 1, 2))(q, k, v)
+    exp = jax.grad(lambda *a: jnp.sum(
+        ref.flash_attention_ref(*a, causal=True) * w), (0, 1, 2))(q, k, v)
+    for g, e in zip(got, exp):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(e), atol=1e-4,
+                                   rtol=1e-4)
 
 
 def test_flash_attention_grad_flows():

@@ -14,12 +14,12 @@ from repro.kernels.flash_attention import flash_attention_fwd
 KEY = jax.random.PRNGKey(0)
 
 
-def _gqa_qkv():
+def _gqa_qkv(hd=128):
     # GQA at the kernels' smallest lane-aligned shape: 2 blocks of 128 rows
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (1, 256, 4, 128), jnp.float32)
-    k = jax.random.normal(ks[1], (1, 256, 2, 128), jnp.float32)
-    v = jax.random.normal(ks[2], (1, 256, 2, 128), jnp.float32)
+    q = jax.random.normal(ks[0], (1, 256, 4, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 256, 2, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 256, 2, hd), jnp.float32)
     return q, k, v
 
 
@@ -44,17 +44,35 @@ def test_flash_attention_lse_smoke():
                                rtol=2e-5)
 
 
-def test_flash_attention_grad_smoke():
-    q, k, v = _gqa_qkv()
+def test_flash_attention_hd64_smoke():
+    # heads of one 64-row tile (hd on sublanes), as granite-4.0-h's
+    q, k, v = _gqa_qkv(hd=64)
+    out = ops.flash_attention(q, k, v, scale=0.015625, block_q=128,
+                              block_k=128, interpret=True)
+    exp = ref.flash_attention_ref(q, k, v, causal=True, scale=0.015625)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=2e-5,
+                               rtol=2e-5)
+
+
+def _grad_matches_ref(hd, scale=None):
+    q, k, v = _gqa_qkv(hd)
     w = jax.random.normal(jax.random.PRNGKey(1), q.shape, jnp.float32)
     got = jax.grad(lambda *a: jnp.sum(ops.flash_attention(
-        *a, block_q=128, block_k=128, interpret=True) * w),
+        *a, scale=scale, block_q=128, block_k=128, interpret=True) * w),
         (0, 1, 2))(q, k, v)
-    exp = jax.grad(lambda *a: jnp.sum(
-        ref.flash_attention_ref(*a, causal=True) * w), (0, 1, 2))(q, k, v)
+    exp = jax.grad(lambda *a: jnp.sum(ref.flash_attention_ref(
+        *a, causal=True, scale=scale) * w), (0, 1, 2))(q, k, v)
     for g, e in zip(got, exp):
         np.testing.assert_allclose(np.asarray(g), np.asarray(e), atol=2e-5,
                                    rtol=2e-5)
+
+
+def test_flash_attention_grad_smoke():
+    _grad_matches_ref(128)
+
+
+def test_flash_attention_hd64_grad_smoke():
+    _grad_matches_ref(64, scale=0.015625)
 
 
 def test_ssd_smoke():
