@@ -39,6 +39,7 @@ the previous boundary.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List, NamedTuple, Optional
 
 from repro.cloud.cost import CostAccountant, CostReport
@@ -260,9 +261,10 @@ class CloudSimulator(Simulator):
                 return 0.0, 0.0, 0.0
             n = float(len(kills))
             # damage concentration: displaced slots per victim job, averaged
-            # over kills (kills that hit empty nodes contribute 0)
+            # over kills (kills that hit empty nodes contribute 0); float
+            # sums are math.fsum so that every Python rounds them alike
             return (sum(k.jobs for k in kills) / n,
-                    sum(k.slots / k.jobs for k in kills if k.jobs) / n,
+                    math.fsum(k.slots / k.jobs for k in kills if k.jobs) / n,
                     sum(k.preempts for k in kills) / n)
         blast_jobs, blast_radius, preempts = _blast_stats(self.kill_blasts)
         zb_jobs, _, zb_preempts = _blast_stats(self.zone_blasts)
@@ -288,17 +290,17 @@ class CloudSimulator(Simulator):
         """Observed (not bid) per-zone spot share: spot slot-hours billed in
         each zone over ALL billed slot-hours — what the fleet actually held,
         for comparison against the bidder's emitted quotas."""
-        total = sum(n.slots * n.billed_hours(self.now)
-                    for n in self.provider.nodes.values())
+        total = math.fsum(n.slots * n.billed_hours(self.now)
+                          for n in self.provider.nodes.values())
         if total <= 0.0:
             return {}
-        per: Dict[str, float] = {}
+        per: Dict[str, List[float]] = {}
         for n in self.provider.nodes.values():
             if n.pool.market == SPOT:
                 h = n.slots * n.billed_hours(self.now)
                 if h > 0.0:
-                    per[n.pool.zone] = per.get(n.pool.zone, 0.0) + h
-        return {z: h / total for z, h in sorted(per.items())}
+                    per.setdefault(n.pool.zone, []).append(h)
+        return {z: math.fsum(hs) / total for z, hs in sorted(per.items())}
 
     def job_region(self, job_id: str) -> Optional[str]:
         """Region hosting the plurality of the job's slots (checkpoint home

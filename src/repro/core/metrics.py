@@ -16,6 +16,7 @@
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -268,9 +269,13 @@ def compute_metrics(jobs: Sequence[JobState], util: UtilizationLog, *,
     submits = [j.spec.submit_time for j in jobs]
     t0 = min(submits) if submits else 0.0
     t1 = max((j.end_time for j in done), default=t0)
+    # float sums are math.fsum: correctly rounded, so every Python agrees
+    # (3.12's sum() compensates, earlier versions' does not)
     wsum = sum(j.spec.priority for j in done) or 1.0
-    resp = sum(j.spec.priority * (response_time(j) or 0.0) for j in done) / wsum
-    comp = sum(j.spec.priority * (completion_time(j) or 0.0) for j in done) / wsum
+    resp = math.fsum(j.spec.priority * (response_time(j) or 0.0)
+                     for j in done) / wsum
+    comp = math.fsum(j.spec.priority * (completion_time(j) or 0.0)
+                     for j in done) / wsum
     phase_kw = {}
     if phases is not None:
         from repro.obs.critical_path import rollup

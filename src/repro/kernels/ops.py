@@ -1,20 +1,19 @@
 """Jitted wrappers around the Pallas kernels.
 
 Each kernel compiles natively where it is lowered for a TPU and runs in
-interpret mode elsewhere (``kernels/platform.py``).
+interpret mode elsewhere (``kernels/platform.py``).  How each is reached:
 
-Attention needs no switch: ``causal_attention`` takes the Pallas flash op
-(forward and backward kernels, ``kernels/flash_attention.py``) where the call
-is lowered for a TPU and ``kernels/blocked.py``'s XLA scan elsewhere;
-``models/attention.py`` sends it only the shapes the kernels take.  The
-model takes the SSD kernel only where ``pallas_enabled()``, off unless
-``REPRO_USE_PALLAS=1`` or ``set_pallas(True)``: off, it runs the jnp scan.
-No model path calls the RMSNorm kernel.
+- ``causal_attention``: ``models/attention.py`` sends it the shapes the
+  kernels take; it is the Pallas flash op (forward and backward kernels,
+  ``kernels/flash_attention.py``) where the call is lowered for a TPU and
+  ``kernels/blocked.py``'s XLA scan elsewhere.
+- ``ssd``: the Pallas SSD forward kernel (``kernels/ssd_scan.py``), whose
+  backward re-runs the jnp chunked scan.  Only tests call it; the model runs
+  ``models/ssm.ssd_chunked`` on every platform.
 """
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
@@ -22,18 +21,7 @@ import jax
 from repro.kernels.blocked import blocked_attention
 from repro.kernels.flash_attention import (flash_attention_bwd,
                                            flash_attention_fwd)
-from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_chunked_pallas
-
-_STATE = {"enabled": os.environ.get("REPRO_USE_PALLAS", "0") == "1"}
-
-
-def pallas_enabled() -> bool:
-    return _STATE["enabled"]
-
-
-def set_pallas(enabled: bool):
-    _STATE["enabled"] = enabled
 
 
 # ---------------------------------------------------------------------------
@@ -112,11 +100,3 @@ _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 def ssd(x, dt, a_log, b, c, *, chunk: int = 128,
         interpret: Optional[bool] = None):
     return _ssd(x, dt, a_log, b, c, chunk, interpret)
-
-
-# ---------------------------------------------------------------------------
-# RMSNorm
-# ---------------------------------------------------------------------------
-
-def rmsnorm(x, w, *, eps: float = 1e-5, interpret: Optional[bool] = None):
-    return rmsnorm_pallas(x, w, eps=eps, interpret=interpret)

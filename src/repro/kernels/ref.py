@@ -50,9 +50,3 @@ def ssd_ref(x, dt, a_log, b, c):
     _, ys = jax.lax.scan(step, h0, (xf.swapaxes(0, 1), dtf.swapaxes(0, 1),
                                     bh.swapaxes(0, 1), ch.swapaxes(0, 1)))
     return ys.swapaxes(0, 1).astype(x.dtype)             # (B,L,H,P)
-
-
-def rmsnorm_ref(x, w, eps: float = 1e-5):
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(x.dtype)

@@ -167,14 +167,13 @@ def abstract_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dic
 
 
 def mla_forward(cfg: ModelConfig, p: dict, x, *, positions, mode: str,
-                cache: Optional[dict] = None, pos=None,
-                absorb: bool = False):
+                cache: Optional[dict] = None, pos=None):
     """Multi-head latent attention. The cache stores only the compressed
     per-token latent (kv_lora_rank + rope_dim floats) — MLA's memory win.
 
-    absorb=True uses the W_UK-absorption decode path (beyond-paper §Perf
-    optimization): scores are computed directly against the latent cache
-    without expanding per-head keys/values.
+    Decode absorbs W_UK into the query and W_UV into the output: scores and
+    context are computed against the latent cache, never expanding per-head
+    keys or values.
     """
     a = cfg.mla
     B, S, D = x.shape
@@ -207,16 +206,12 @@ def mla_forward(cfg: ModelConfig, p: dict, x, *, positions, mode: str,
         new_cache = {"ckv": ckv_c, "krope": kr_c}
         ckv_all, krope_all = ckv_c, kr_c
         kv_len = pos + S
-        q_pos0 = pos
     else:
         new_cache = {"ckv": ckv, "krope": krope} if mode == "prefill" else None
         ckv_all, krope_all = ckv, krope
-        kv_len = None
-        q_pos0 = 0
 
     scale = (nope + rope_d) ** -0.5
-    Sk = ckv_all.shape[1]
-    tpos = jnp.arange(Sk)
+    tpos = jnp.arange(ckv_all.shape[1])
     neg = jnp.finfo(jnp.float32).min
     w_uk = p["wkv_b"][..., :nope]          # (lora, H, nope)
     w_uv = p["wkv_b"][..., nope:]          # (lora, H, vd)
@@ -238,30 +233,18 @@ def mla_forward(cfg: ModelConfig, p: dict, x, *, positions, mode: str,
         y = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
         return y, new_cache
 
-    if absorb:
-        # fold W_UK into the query; score directly against the latent cache
-        q_lat = jnp.einsum("bshn,lhn->bshl", q_nope, w_uk)
-        scores = (jnp.einsum("bshl,btl->bhst", q_lat, ckv_all) +
-                  jnp.einsum("bshr,btr->bhst", q_rope, krope_all))
-    else:
-        k_nope = jnp.einsum("btl,lhn->bthn", ckv_all, w_uk)
-        scores = (jnp.einsum("bshn,bthn->bhst", q_nope, k_nope) +
-                  jnp.einsum("bshr,btr->bhst", q_rope, krope_all))
+    # fold W_UK into the query; score directly against the latent cache
+    q_lat = jnp.einsum("bshn,lhn->bshl", q_nope, w_uk)
+    scores = (jnp.einsum("bshl,btl->bhst", q_lat, ckv_all) +
+              jnp.einsum("bshr,btr->bhst", q_rope, krope_all))
     scores = scores.astype(jnp.float32) * scale
-    if mode != "decode" or True:  # causal always (decode masks cache tail too)
-        spos = q_pos0 + jnp.arange(S)
-        mask = spos[:, None] >= tpos[None, :]
-        scores = jnp.where(mask[None, None], scores, neg)
-    if kv_len is not None:
-        scores = jnp.where((tpos < kv_len)[None, None, None], scores, neg)
+    # causal, and the cache tail from kv_len on is masked too
+    mask = (pos + jnp.arange(S))[:, None] >= tpos[None, :]
+    scores = jnp.where(mask[None, None], scores, neg)
+    scores = jnp.where((tpos < kv_len)[None, None, None], scores, neg)
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-
-    if absorb:
-        ctx_lat = jnp.einsum("bhst,btl->bshl", probs, ckv_all)
-        out = jnp.einsum("bshl,lhv->bshv", ctx_lat, w_uv)
-    else:
-        vfull = jnp.einsum("btl,lhv->bthv", ckv_all, w_uv)
-        out = jnp.einsum("bhst,bthv->bshv", probs, vfull)
+    ctx_lat = jnp.einsum("bhst,btl->bshl", probs, ckv_all)
+    out = jnp.einsum("bshl,lhv->bshv", ctx_lat, w_uv)
     out = shard_constraint(out, "batch", None, "heads", None)
     y = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
     return y, new_cache

@@ -1,16 +1,15 @@
 """Mixture-of-experts layer.
 
-Two implementations sharing one router:
+``moe_forward`` dispatches by gather: capacity-bounded dispatch via sort +
+gather into an (E, C, D) buffer, grouped einsum per expert, and scatter-add
+combine.  FLOPs ~ active compute x capacity_factor.  Pure GSPMD-friendly ops
+(sort/gather/einsum/scatter) — the expert axis shards over 'model' (expert
+parallelism), and XLA inserts the token exchange collectives.
 
-- ``dense``: every expert computes every token, combined by top-k weights.
-  Exact (no token dropping), simple, used as the correctness oracle and on
-  tiny smoke configs.  FLOPs = num_experts/top_k x the active compute.
-- ``gather`` (default at scale): capacity-bounded dropless-ish dispatch via
-  sort + gather into an (E, C, D) buffer, grouped einsum per expert, and
-  scatter-add combine.  FLOPs ~ active compute x capacity_factor.  Pure
-  GSPMD-friendly ops (sort/gather/einsum/scatter) — the expert axis shards
-  over 'model' (expert parallelism), and XLA inserts the token exchange
-  collectives.  §Perf compares an explicit shard_map all-to-all variant.
+``moe_dense`` is the reference it is tested against: the same router and
+top-k, every expert computing every token, combined by the top-k weights.
+Exact (no token dropping); FLOPs = num_experts/top_k x the active compute.
+Only tests call it.
 
 Expert weights are stacked (E, D, F); the layer is fully differentiable.
 """
@@ -25,13 +24,6 @@ from repro.configs.base import FF_SWIGLU, ModelConfig
 from repro.models.layers import apply_ffn
 from repro.sharding import shard_constraint
 
-_IMPL = {"impl": "gather"}  # module switch: "gather" | "dense"
-
-
-def set_moe_impl(impl: str):
-    assert impl in ("gather", "dense")
-    _IMPL["impl"] = impl
-
 
 def router_probs(p: dict, x) -> jax.Array:
     """x: (B,S,D) -> fp32 probs (B,S,E)."""
@@ -44,7 +36,7 @@ def load_balance_loss(probs, expert_ids, num_experts: int) -> jax.Array:
     """Switch-transformer aux loss: E * sum_e f_e * P_e (fp32 scalar).
 
     Counts via per-row bincount — a (B*S*k, E) one-hot would cost 4 GB on
-    deepseek train_4k (see EXPERIMENTS.md §Perf)."""
+    deepseek train_4k."""
     pe = jnp.mean(probs.reshape(-1, num_experts), axis=0)
     B = expert_ids.shape[0]
     ids2 = expert_ids.reshape(B, -1)
@@ -68,11 +60,9 @@ def _expert_ffn_batched(xg, p, ff_kind: str):
     return jnp.einsum("becf,efd->becd", h, p["w_down"])
 
 
-def _moe_dense(cfg: ModelConfig, p: dict, x, probs, weights, ids):
+def _dense_experts(cfg: ModelConfig, p: dict, x, weights, ids):
     """All-experts path: (B,S,E) combine weights, exact."""
     m = cfg.moe
-    B, S, D = x.shape
-    k = m.experts_per_token
     comb = jnp.sum(jax.nn.one_hot(ids, m.num_experts, dtype=jnp.float32)
                    * weights[..., None].astype(jnp.float32), axis=2)  # (B,S,E)
     if m.ff_kind == FF_SWIGLU:
@@ -86,7 +76,7 @@ def _moe_dense(cfg: ModelConfig, p: dict, x, probs, weights, ids):
     return jnp.einsum("bsed,bse->bsd", y, comb.astype(x.dtype))
 
 
-def _moe_gather(cfg: ModelConfig, p: dict, x, probs, weights, ids):
+def _moe_gather(cfg: ModelConfig, p: dict, x, weights, ids):
     """Capacity-bounded dispatch, *per sequence* (GShard-style groups).
 
     Routing/sort/scatter happen independently per batch row, so under GSPMD
@@ -148,7 +138,7 @@ def _moe_gather(cfg: ModelConfig, p: dict, x, probs, weights, ids):
 
     # gather via clamp+mask — a sentinel row (concatenate to S+1) makes the
     # seq dim indivisible and GSPMD replicates the FULL global batch in f32
-    # (21.5 GB/device/buffer on deepseek train_4k — EXPERIMENTS.md §Perf)
+    # (21.5 GB/device/buffer on deepseek train_4k)
     idx_flat = idx.reshape(B, E_pad * cap)
     occupied = idx_flat < S                                       # (B, E*C)
     idx_safe = jnp.minimum(idx_flat, S - 1)
@@ -164,21 +154,26 @@ def _moe_gather(cfg: ModelConfig, p: dict, x, probs, weights, ids):
     return y
 
 
-def moe_forward(cfg: ModelConfig, p: dict, x) -> Tuple[jax.Array, jax.Array]:
-    """Returns (y, aux_loss). x: (B,S,D)."""
+def _moe(cfg: ModelConfig, p: dict, x, experts):
     m = cfg.moe
     probs = router_probs(p, x)                                        # fp32
     weights, ids = jax.lax.top_k(probs, m.experts_per_token)          # (B,S,k)
     weights = (weights / jnp.maximum(
         weights.sum(-1, keepdims=True), 1e-9)).astype(x.dtype)
     aux = load_balance_loss(probs, ids, m.num_experts) * m.router_aux_weight
-
-    impl = _IMPL["impl"]
-    if impl == "dense":
-        y = _moe_dense(cfg, p, x, probs, weights, ids)
-    else:
-        y = _moe_gather(cfg, p, x, probs, weights, ids)
-
+    y = experts(cfg, p, x, weights, ids)
     if m.num_shared_experts:
         y = y + apply_ffn(p["shared"], x, m.ff_kind)
     return y, aux
+
+
+def moe_forward(cfg: ModelConfig, p: dict, x) -> Tuple[jax.Array, jax.Array]:
+    """Returns (y, aux_loss). x: (B,S,D)."""
+    return _moe(cfg, p, x, _moe_gather)
+
+
+def moe_dense(cfg: ModelConfig, p: dict, x) -> Tuple[jax.Array, jax.Array]:
+    """``moe_forward`` with every expert computing every token, so no token
+    is dropped: the reference that tests hold the gather dispatch to.
+    Returns (y, aux_loss)."""
+    return _moe(cfg, p, x, _dense_experts)

@@ -6,10 +6,10 @@ The SSD recurrence per head (state N, head dim P):
 
 Training/prefill uses the *chunked* algorithm: quadratic attention-like
 computation inside chunks of Q tokens (MXU-friendly) plus a cheap inter-chunk
-state recurrence — this is the TPU-native adaptation of the paper's GPU scan
-(DESIGN.md §2).  ``repro.kernels.ssd_scan`` is the Pallas version of the
-chunked core; this module is the jnp path (identical math) used on CPU and by
-the dry-run.
+state recurrence — the TPU-native adaptation of the GPU scan.  This
+module's jnp ``ssd_chunked`` is the path every platform takes;
+``repro.kernels.ssd_scan`` is a Pallas version of the same chunked core that
+no model path calls.
 
 Decode keeps (conv window, h state) per layer in the cache pytree.
 """
@@ -69,11 +69,9 @@ def ssd_chunked(x, dt, a_log, b, c, *, chunk: int):
     Chunks are processed by a sequential ``lax.scan`` carrying the (B,H,P,N)
     state — only ONE chunk's quadratic (B,Q,Q,H) tensors are ever live
     (materializing all chunks at once costs O(L*Q) memory: 34 TB global on
-    mamba2 train_4k — see EXPERIMENTS.md §Perf).  The chunk body is
-    checkpointed so the backward pass recomputes those tensors per chunk.
+    mamba2 train_4k).  The chunk body is checkpointed so the backward pass
+    recomputes those tensors per chunk.
     """
-    from repro.models.transformer import _SCAN  # unroll flag (cost lowers)
-
     B, L, H, P = x.shape
     G, N = b.shape[2], b.shape[3]
     rep = H // G
@@ -117,8 +115,7 @@ def ssd_chunked(x, dt, a_log, b, c, *, chunk: int):
 
     body = jax.checkpoint(body)
     h0 = jnp.zeros((B, H, P, N), f32)
-    _, ys = jax.lax.scan(body, h0, (xc, dtc, bc, cc),
-                         unroll=nc if _SCAN["unroll"] else 1)
+    _, ys = jax.lax.scan(body, h0, (xc, dtc, bc, cc))
     y = ys.swapaxes(0, 1).reshape(B, L, H, P)
     return y.astype(x.dtype)
 
@@ -182,12 +179,8 @@ def ssm_forward(cfg: ModelConfig, p: dict, xin, *, mode: str,
         y = jnp.einsum("bhpn,bhn->bhp", h, ch)[:, None]       # (B,1,H,P)
         new_cache = {"conv": new_conv, "h": h}
     else:
-        from repro.kernels import ops as kops
         with jax.named_scope("ssd"):
-            if kops.pallas_enabled():
-                y = kops.ssd(xs, dt, p["a_log"], b, c, chunk=ss.chunk)
-            else:
-                y = ssd_chunked(xs, dt, p["a_log"], b, c, chunk=ss.chunk)
+            y = ssd_chunked(xs, dt, p["a_log"], b, c, chunk=ss.chunk)
         if mode == "prefill":
             h = ssd_final_state(xs, dt, p["a_log"], b, chunk=ss.chunk)
             new_cache = {"conv": new_conv, "h": h}

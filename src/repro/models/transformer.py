@@ -6,8 +6,7 @@ bounded at 60-layer/512-device scale.  Training remats each scanned block.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -19,31 +18,10 @@ from repro.models import ssm as ssm_mod
 from repro.models.layers import rmsnorm
 from repro.sharding import shard_constraint
 
-_REMAT = {"policy": "full"}   # none | full | dots  (§Perf knob)
-_MLA_ABSORB = {"decode": True, "prefill": False, "train": False}
-_SCAN = {"unroll": False}     # True: unroll layer scan (cost-composition lowers)
-
-
-def set_remat(policy: str):
-    assert policy in ("none", "full", "dots")
-    _REMAT["policy"] = policy
-
-
-def set_scan_unroll(unroll: bool):
-    _SCAN["unroll"] = unroll
-
-
-def set_mla_absorb(mode: str, value: bool):
-    _MLA_ABSORB[mode] = value
-
 
 def _maybe_remat(fn, mode: str):
-    if mode != "train" or _REMAT["policy"] == "none":
-        return fn
-    if _REMAT["policy"] == "dots":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-    return jax.checkpoint(fn)
+    """Training recomputes ``fn``'s activations in its backward pass."""
+    return jax.checkpoint(fn) if mode == "train" else fn
 
 
 # ---------------------------------------------------------------------------
@@ -77,7 +55,7 @@ def apply_layer(cfg: ModelConfig, p: dict, x, layer_idx: int, *, positions,
         elif mixer == MLA:
             y, kvc = attn_mod.mla_forward(
                 cfg, p["mixer"], h, positions=positions, mode=mode,
-                cache=c_in.get("kv"), pos=pos, absorb=_MLA_ABSORB[mode])
+                cache=c_in.get("kv"), pos=pos)
             new_cache["kv"] = kvc
         elif mixer == SSM:
             y, sc = ssm_mod.ssm_forward(cfg, p["mixer"], h, mode=mode,
@@ -157,7 +135,7 @@ def decoder(cfg: ModelConfig, dparams: dict, x, *, positions, mode: str,
     if scan_n:
         # hybrids (period > 1) remat each SUB-layer: rematting the whole
         # 8-layer Jamba block keeps all 8 layers' intermediates live during
-        # its backward (150 GB/chip before this — EXPERIMENTS.md §Perf)
+        # its backward (150 GB/chip before this)
         def sub_fn(x, lp, c, j):
             return apply_layer(cfg, lp, x, prefix_n + j, positions=positions,
                                mode=mode, cache=c, pos=pos, enc_out=enc_out)
@@ -192,15 +170,13 @@ def decoder(cfg: ModelConfig, dparams: dict, x, *, positions, mode: str,
             return (x, aux_acc + aux_b), bnc
 
         bc0 = cache["blocks"] if cache else None
-        unroll = (scan_n // period) if _SCAN["unroll"] else 1
         if bc0 is None:
             (x, aux_total), blocks_cache = jax.lax.scan(
                 lambda c, bp: scan_body(c, (bp, None)),
-                (x, aux_total), dparams["blocks"], unroll=unroll)
+                (x, aux_total), dparams["blocks"])
         else:
             (x, aux_total), blocks_cache = jax.lax.scan(
-                scan_body, (x, aux_total), (dparams["blocks"], bc0),
-                unroll=unroll)
+                scan_body, (x, aux_total), (dparams["blocks"], bc0))
         if blocks_cache is not None:
             new_cache["blocks"] = blocks_cache
 
@@ -223,8 +199,6 @@ def encoder(cfg: ModelConfig, eparams: dict, x, *, positions, mode: str):
         return shard_constraint(x, "batch", "seq", "embed")
 
     layer_fn = _maybe_remat(layer_fn, mode)
-    n = jax.tree.leaves(eparams["blocks"])[0].shape[0]
     x, _ = jax.lax.scan(lambda c, lp: (layer_fn(c, lp), None),
-                        x, eparams["blocks"],
-                        unroll=n if _SCAN["unroll"] else 1)
+                        x, eparams["blocks"])
     return rmsnorm(x, eparams["final_norm"], cfg.norm_eps)

@@ -2,7 +2,8 @@
 prefill, at shapes the flash kernels' blocks tile (head sizes a multiple of
 64, lengths of 128) and on one device, goes through
 ``kernels.ops.causal_attention``, which takes the Pallas op only where the
-call is lowered for a TPU; every other call keeps its path."""
+call is lowered for a TPU; every other call keeps its path.  MLA decode
+scores against its latent cache."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -101,3 +102,51 @@ def test_cpu_train_step_lowers_to_the_blocked_scan():
         cfg, p, x, positions=pos, mode="train"))(p, x)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(jax.jit(blocked)(p, x)))
+
+
+def test_mla_decode_matches_expanded_keys():
+    # decode folds W_UK into the query and W_UV into the output, scoring
+    # against the latent cache; the oracle expands per-head keys and values
+    # from that cache, at deepseek-v2's smoke widths
+    from repro.models.layers import apply_rope, rmsnorm
+    cfg = smoke_config("deepseek-v2-236b").with_(dtype="float32")
+    a, eps = cfg.mla, cfg.norm_eps
+    p = init_params(cfg, jax.random.PRNGKey(0))
+    p = p["decoder"]["prefix"]["layer0"]["mixer"]
+    B, T, pos = 2, 16, 5
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    x = jax.random.normal(ks[0], (B, 1, cfg.d_model))
+    cache = {"ckv": jax.random.normal(ks[1], (B, T, a.kv_lora_rank)),
+             "krope": jax.random.normal(ks[2], (B, T, a.qk_rope_head_dim))}
+    positions = pos + jnp.arange(1)
+    y, new_cache = A.mla_forward(cfg, p, x, positions=positions,
+                                 mode="decode", cache=cache,
+                                 pos=jnp.int32(pos))
+
+    nope = a.qk_nope_head_dim
+    q = jnp.einsum("bsl,lhk->bshk", rmsnorm(
+        jnp.einsum("bsd,dl->bsl", x, p["wq_a"]), p["q_norm"], eps),
+        p["wq_b"])
+    q_nope = q[..., :nope]
+    q_rope = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    ckv_kr = jnp.einsum("bsd,dl->bsl", x, p["wkv_a"])
+    ckv = rmsnorm(ckv_kr[..., :a.kv_lora_rank], p["kv_norm"], eps)
+    krope = apply_rope(ckv_kr[:, :, None, a.kv_lora_rank:], positions,
+                       cfg.rope_theta)[:, :, 0]
+    ckv_all = cache["ckv"].at[:, pos].set(ckv[:, 0])
+    krope_all = cache["krope"].at[:, pos].set(krope[:, 0])
+    k_nope = jnp.einsum("btl,lhn->bthn", ckv_all, p["wkv_b"][..., :nope])
+    v = jnp.einsum("btl,lhv->bthv", ckv_all, p["wkv_b"][..., nope:])
+    scores = (jnp.einsum("bshn,bthn->bhst", q_nope, k_nope) +
+              jnp.einsum("bshr,btr->bhst", q_rope, krope_all))
+    scores = scores * (nope + a.qk_rope_head_dim) ** -0.5
+    scores = jnp.where(jnp.arange(T) <= pos, scores, -jnp.inf)
+    out = jnp.einsum("bhst,bthv->bshv", jax.nn.softmax(scores, axis=-1), v)
+    want = jnp.einsum("bshv,hvd->bsd", out, p["wo"])
+
+    np.testing.assert_array_equal(np.asarray(new_cache["ckv"]),
+                                  np.asarray(ckv_all))
+    np.testing.assert_array_equal(np.asarray(new_cache["krope"]),
+                                  np.asarray(krope_all))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5,
+                               rtol=1e-5)

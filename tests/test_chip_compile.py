@@ -106,11 +106,6 @@ def test_pack_kernel_compiles(one_chip, dtype):
              *shapes)
 
 
-def test_rmsnorm_compiles_at_d4096(one_chip):
-    _compile(lambda x, w: ops.rmsnorm(x, w), one_chip,
-             ((4096, 4096), jnp.float32), ((4096,), jnp.float32))
-
-
 def test_ssd_compiles_at_mamba2_widths(one_chip):
     # mamba2-1.3b: d_inner 4096 = 64 heads x head_dim 64, d_state 128,
     # one B/C group, chunk 128

@@ -7,6 +7,8 @@ by path exactly as the benchmark installs them.
 """
 import copy
 import os
+import subprocess
+import sys
 import time
 
 import jax
@@ -17,7 +19,8 @@ import pytest
 from bench.harness import driver, program, spec, weights
 from bench.models import dense_gqa, granite_hybrid, mamba2
 from repro.core.elastic import ElasticTrainer, TrainJobConfig
-from repro.models import init_params, loss_fn
+from repro.models import (abstract_params, forward_hidden, init_params,
+                          loss_fn)
 from repro.models.ssm import ssd_chunked
 
 SEED = 2**33 + 5
@@ -148,6 +151,63 @@ def test_compiled_granite_step_carries_the_ssd_and_conv_scopes():
         assert scope in text, scope
 
 
+def _remats(jaxpr) -> int:
+    """Checkpoint equations in ``jaxpr`` and the bodies it calls (scans,
+    nested jits), not counting those inside another checkpoint."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("checkpoint", "remat2"):
+            n += 1
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _remats(sub)
+    return n
+
+
+# GRANITE: one scanned block of an SSM and an attention layer, each held on
+# its own in train; the SSD chunk body is checkpointed in every mode (in
+# train, inside its layer's checkpoint).  DENSE: the scanned block is one
+# layer.
+@pytest.mark.parametrize("conf, per_layer, always", [(GRANITE, 2, 1),
+                                                     (DENSE, 1, 0)])
+def test_train_remats_each_layer_and_serving_does_not(conf, per_layer,
+                                                      always):
+    cfg = program.model_config(conf)
+    tok = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    counts = {mode: _remats(jax.make_jaxpr(
+        lambda p, t: forward_hidden(cfg, p, {"tokens": t}, mode=mode))(
+            abstract_params(cfg), tok).jaxpr)
+        for mode in ("train", "prefill")}
+    assert counts == {"train": per_layer, "prefill": always}
+
+
+def test_environment_selects_no_kernel():
+    # the model step reads no environment: with a kernel-selecting
+    # variable set, the train step's lowering holds the jnp SSD scope and
+    # no ssd_scan call
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.configs import smoke_config\n"
+        "from repro.models import abstract_params, loss_fn\n"
+        "cfg = smoke_config('granite-4.0-h-micro').with_(dtype='float32')\n"
+        "t = jax.ShapeDtypeStruct((1, 32), jnp.int32)\n"
+        "g = jax.grad(lambda p, t: loss_fn(cfg, p, {'tokens': t, "
+        "'labels': t})[0])\n"
+        "text = jax.jit(g).lower(abstract_params(cfg), t).as_text("
+        "debug_info=True)\n"
+        "print('ssd_scan' in text, 'mixer/ssd' in text)\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, REPRO_USE_PALLAS="1", JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(root, "src"))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == ["False", "True"], out.stdout
+
+
 def test_tiny_granite_cell_runs_correct_through_the_harness():
     # the cell's own files and layout (8 layers, attention at 5, all held
     # one by one), at widths and a sequence a CPU test run holds
@@ -167,7 +227,6 @@ def test_tiny_granite_cell_runs_correct_through_the_harness():
 
 @pytest.mark.parametrize("n_layers, prefix", [(8, 8), (10, 0), (20, 0)])
 def test_reference_layout_is_the_programs(n_layers, prefix):
-    from repro.models import abstract_params
     conf = dict(spec.load_json(
         os.path.join(spec.BENCH, "configs", "granite4h_micro.json")),
         num_layers=n_layers)

@@ -137,18 +137,6 @@ def test_ssd_grad_matches_chunked_jnp():
                                rtol=1e-4)
 
 
-@pytest.mark.parametrize("shape", [(7, 64), (8, 33, 128), (2, 3, 4, 256)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_rmsnorm_kernel(shape, dtype):
-    x = jax.random.normal(KEY, shape, dtype)
-    w = jax.random.normal(jax.random.PRNGKey(1), shape[-1:], jnp.float32)
-    out = ops.rmsnorm(x, w, interpret=True)
-    exp = ref.rmsnorm_ref(x, w)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-6
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(exp, np.float32), atol=tol, rtol=tol)
-
-
 # ---------------------------------------------------------------------------
 # blocked (XLA) flash twin
 # ---------------------------------------------------------------------------

@@ -75,7 +75,9 @@ def test_flash_attention_hd64_grad_smoke():
     _grad_matches_ref(64, scale=0.015625)
 
 
-def test_ssd_smoke():
+# interpret=None: CPU arrays select interpret mode by themselves
+@pytest.mark.parametrize("interpret", [True, None])
+def test_ssd_smoke(interpret):
     ks = jax.random.split(KEY, 5)
     B, L, H, P, G, N = 1, 32, 2, 8, 1, 8
     x = jax.random.normal(ks[0], (B, L, H, P)) * 0.5
@@ -83,19 +85,7 @@ def test_ssd_smoke():
     a_log = jnp.log(jax.random.uniform(ks[2], (H,), minval=1.0, maxval=8.0))
     b = jax.random.normal(ks[3], (B, L, G, N)) * 0.3
     c = jax.random.normal(ks[4], (B, L, G, N)) * 0.3
-    out = ops.ssd(x, dt, a_log, b, c, chunk=16, interpret=True)
+    out = ops.ssd(x, dt, a_log, b, c, chunk=16, interpret=interpret)
     exp = ref.ssd_ref(x, dt, a_log, b, c)
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-4,
                                rtol=1e-4)
-
-
-# interpret=None: CPU arrays select interpret mode by themselves
-@pytest.mark.parametrize("interpret", [True, None])
-def test_rmsnorm_smoke(interpret):
-    x = jax.random.normal(KEY, (7, 64), jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(1), (64,), jnp.float32)
-    out = ops.rmsnorm(x, w, interpret=interpret)
-    exp = ref.rmsnorm_ref(x, w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(exp), atol=1e-6,
-                               rtol=1e-6)
-

@@ -14,7 +14,7 @@ from repro.configs import ALL_ARCHS, smoke_config
 from repro.models import (decode_step, forward_hidden, init_params, loss_fn,
                           pad_cache, prefill)
 from repro.models.model import _head_input, _head_weight
-from repro.models.moe import moe_forward, set_moe_impl
+from repro.models.moe import moe_dense, moe_forward
 
 KEY = jax.random.PRNGKey(0)
 
@@ -52,33 +52,33 @@ def test_smoke_forward_and_train_step(arch):
                                   "granite-4.0-h-micro"])
 def test_decode_matches_teacher_forcing(arch):
     """prefill + step-by-step decode reproduces the full-forward logits
-    (with the exact dense-MoE path — capacity dispatch is batch-dependent)."""
-    set_moe_impl("dense")
-    try:
-        cfg = smoke_config(arch).with_(dtype="float32")
-        params = init_params(cfg, jax.random.PRNGKey(42))
-        B, S, S0 = 2, 16, 8
-        toks = jax.random.randint(jax.random.PRNGKey(7), (B, S), 0,
-                                  cfg.vocab_size)
-        fb = {"tokens": toks, "labels": toks}
-        if cfg.enc_layers:
-            fb["enc_embeds"] = jax.random.normal(KEY, (B, 8, cfg.d_model))
-        hid, _ = forward_hidden(cfg, params, fb, mode="train")
-        full = jnp.einsum("bsd,dv->bsv", _head_input(cfg, hid),
-                          _head_weight(cfg, params))[..., :cfg.vocab_size]
-        pb = {"tokens": toks[:, :S0]}
-        if cfg.enc_layers:
-            pb["enc_embeds"] = fb["enc_embeds"]
-        cache, logits = prefill(cfg, params, pb)
-        cache = pad_cache(cfg, cache, S0, S)
-        errs = [float(jnp.max(jnp.abs(logits - full[:, S0 - 1])))]
-        for t in range(S0, S):
-            logits, cache = decode_step(cfg, params, cache, toks[:, t:t + 1],
-                                        jnp.int32(t))
-            errs.append(float(jnp.max(jnp.abs(logits - full[:, t]))))
-        assert max(errs) < 2e-4, errs
-    finally:
-        set_moe_impl("gather")
+    (MoE archs at a capacity that drops no token — capacity dispatch is
+    batch-dependent)."""
+    cfg = smoke_config(arch).with_(dtype="float32")
+    if cfg.moe is not None:
+        cfg = cfg.with_(moe=dataclasses.replace(cfg.moe,
+                                                capacity_factor=100.0))
+    params = init_params(cfg, jax.random.PRNGKey(42))
+    B, S, S0 = 2, 16, 8
+    toks = jax.random.randint(jax.random.PRNGKey(7), (B, S), 0,
+                              cfg.vocab_size)
+    fb = {"tokens": toks, "labels": toks}
+    if cfg.enc_layers:
+        fb["enc_embeds"] = jax.random.normal(KEY, (B, 8, cfg.d_model))
+    hid, _ = forward_hidden(cfg, params, fb, mode="train")
+    full = jnp.einsum("bsd,dv->bsv", _head_input(cfg, hid),
+                      _head_weight(cfg, params))[..., :cfg.vocab_size]
+    pb = {"tokens": toks[:, :S0]}
+    if cfg.enc_layers:
+        pb["enc_embeds"] = fb["enc_embeds"]
+    cache, logits = prefill(cfg, params, pb)
+    cache = pad_cache(cfg, cache, S0, S)
+    errs = [float(jnp.max(jnp.abs(logits - full[:, S0 - 1])))]
+    for t in range(S0, S):
+        logits, cache = decode_step(cfg, params, cache, toks[:, t:t + 1],
+                                    jnp.int32(t))
+        errs.append(float(jnp.max(jnp.abs(logits - full[:, t]))))
+    assert max(errs) < 2e-4, errs
 
 
 def test_moe_gather_matches_dense_at_high_capacity():
@@ -87,9 +87,7 @@ def test_moe_gather_matches_dense_at_high_capacity():
     params = init_params(cfg, KEY)
     p = jax.tree.map(lambda a: a[0], params["decoder"]["blocks"])["sub0"]["ff"]
     x = jax.random.normal(KEY, (2, 16, cfg.d_model)) * 0.3
-    set_moe_impl("dense")
-    yd, auxd = moe_forward(cfg, p, x)
-    set_moe_impl("gather")
+    yd, auxd = moe_dense(cfg, p, x)
     yg, auxg = moe_forward(cfg, p, x)
     np.testing.assert_allclose(np.asarray(yd), np.asarray(yg), atol=1e-5,
                                rtol=1e-5)
